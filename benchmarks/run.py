@@ -47,18 +47,22 @@ def main() -> None:
 
     import importlib
     t0 = time.time()
+    failed = []
     for title, mod_name in SECTIONS:
         if args.only and args.only != mod_name:
             continue
         print(f"\n{'='*78}\n{title}\n{'='*78}")
-        mod = importlib.import_module(f"benchmarks.{mod_name}")
         t1 = time.time()
         try:
-            mod.main(quick=quick)
-        except Exception as e:          # keep the report going
+            importlib.import_module(f"benchmarks.{mod_name}").main(
+                quick=quick)
+        except Exception as e:  # report every section, then fail the run
             print(f"SECTION FAILED: {type(e).__name__}: {e}")
+            failed.append(mod_name)
         print(f"[{mod_name}: {time.time()-t1:.1f}s]")
     print(f"\ntotal: {time.time()-t0:.1f}s")
+    if failed:
+        sys.exit(f"{len(failed)} section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,21 +1,15 @@
 """Dispatching wrapper for flash attention.
 
-Model layout in/out: [B, S, H, D].  TPU -> Pallas kernel; CPU -> jnp ref;
-``REPRO_FORCE_PALLAS_INTERPRET=1`` -> Pallas interpret mode (kernel tests).
+Model layout in/out: [B, S, H, D].  Implementation per
+``kernels.mode.kernel_mode``: the Pallas kernel on a TPU, the jnp ref or
+interpret mode off it.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 from repro.kernels.flash_attention import ref as _ref
-
-
-def _mode():
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET") == "1":
-        return "interpret"
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+from repro.kernels.mode import kernel_mode
 
 
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -23,7 +17,7 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
               window: int = 0, sink: int = 0, sparsity: float = 0.0,
               block_q: int = 128, block_kv: int = 128) -> jax.Array:
     """q [B,Sq,Hq,D]; k,v [B,Skv,Hkv,D] -> [B,Sq,Hq,D]."""
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return _ref.flash_mha_ref(q, k, v, n_kv_heads=n_kv_heads,
                                   causal=causal, q_offset=q_offset,

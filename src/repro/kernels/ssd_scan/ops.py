@@ -1,35 +1,29 @@
 """Dispatching wrapper for the SSD chunked scan.
 
-On TPU the Pallas kernel (``kernel.py``) is used; on CPU the pure-jnp
-oracle (``ref.py``) runs.  ``REPRO_FORCE_PALLAS_INTERPRET=1`` forces the
-Pallas kernel in interpret mode (used by the kernel tests on CPU).
+Implementation per ``kernels.mode.kernel_mode``: the Pallas kernel
+(``kernel.py``) on a TPU; off it the pure-jnp oracle (``ref.py``), or
+the kernel in interpret mode under ``REPRO_FORCE_PALLAS_INTERPRET=1``.
 """
 from __future__ import annotations
 
-import os
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 
+from repro.kernels.mode import kernel_mode
 from repro.kernels.ssd_scan import ref as _ref
-
-
-def _use_pallas() -> Optional[bool]:
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET") == "1":
-        return None          # pallas, interpret mode
-    return jax.default_backend() == "tpu"
 
 
 def ssd(x, dt, A, Bm, Cm, *, chunk: int = 128,
         init_state=None) -> Tuple[jax.Array, jax.Array]:
-    mode = _use_pallas()
-    if mode is False:
+    mode = kernel_mode()
+    if mode == "ref":
         return _ref.ssd_ref(x, dt, A, Bm, Cm, chunk=chunk,
                             init_state=init_state)
     from repro.kernels.ssd_scan import kernel as _k
     return _k.ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk,
                          init_state=init_state,
-                         interpret=(mode is None))
+                         interpret=(mode == "interpret"))
 
 
 def ssd_decode(x, dt, A, Bm, Cm, state):

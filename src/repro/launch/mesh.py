@@ -10,10 +10,18 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the sharding rules constrain
+    activations with ``with_sharding_constraint``, which only names
+    Auto axes (``jax.make_mesh`` defaults to Explicit ones)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None):
@@ -24,4 +32,4 @@ def make_debug_mesh(n_devices: int | None = None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

@@ -8,7 +8,9 @@ Two modes, ONE workload spec and ONE metrics surface:
     --real     real JAX AR-DiT execution on this host through the
                unified ``serve.session.StreamingSession``: the SAME
                ``ControlPlane.tick()`` decisions as --sim drive actual
-               chunk generation (tiny model), over the same
+               chunk generation (a reduced model, or the --models
+               configs at their published widths with
+               --published-widths: the chip path), over the same
                --workload/--rate/--seed StreamSpec generators, and the
                run prints the same one-line ``Summary.row()`` — so a
                workload can be compared sim-vs-real apples-to-apples.
@@ -30,6 +32,9 @@ Two modes, ONE workload spec and ONE metrics surface:
     PYTHONPATH=src python -m repro.launch.serve --real \
         --models ardit-self-forcing,ardit-causal-forcing \
         --streams 4                # heterogeneous co-serving, one pool
+    PYTHONPATH=src python -m repro.launch.serve --real \
+        --models ardit-self-forcing --published-widths \
+        --streams 2 --chunks 3     # published widths, on an accelerator
 """
 from __future__ import annotations
 
@@ -76,6 +81,12 @@ def main() -> None:
                          "Streams are tagged round-robin; the first "
                          "model is the primary bundle and the report "
                          "adds per-model Summary rows")
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve the --models registry configs at their "
+                         "published widths instead of cfg.reduced() "
+                         "(--real; needs an accelerator's memory): the "
+                         "pool is sized to the device and the playout "
+                         "cadence is the real 0.75 s per chunk")
     ap.add_argument("--chunks", type=int, default=4,
                     help="per-stream chunk cap for --real (the tiny "
                          "model; --sim uses the spec lengths as-is)")
@@ -92,7 +103,8 @@ def main() -> None:
                          "(< 1 compresses Poisson gaps / trace idles)")
     ap.add_argument("--pool-streams", type=int, default=0,
                     help="co-resident stream cap of the paged KV pool "
-                         "(< --streams oversubscribes; 0 -> all fit)")
+                         "(< --streams oversubscribes; 0 -> as many as "
+                         "the device memory holds, at most 16)")
     ap.add_argument("--context-backend", choices=("gather", "paged"),
                     default="paged",
                     help="how sub-batches see cached KV: 'paged' serves "
@@ -124,6 +136,9 @@ def main() -> None:
             ap.error("--models only applies to --real (co-serving rides "
                      "the live batched executor)")
         args.batched = True          # co-serving rides the batched path
+    if args.published_widths and not args.models:
+        ap.error("--published-widths needs --models (the registry configs "
+                 "to serve at their published widths)")
     if args.pool_streams and not (args.real and args.batched):
         ap.error("--pool-streams only applies to --real --batched")
     if any(a.startswith("--context-backend") for a in sys.argv[1:]) \
@@ -151,8 +166,10 @@ def main() -> None:
     from repro.sched_sim.workloads import WORKLOADS
 
     if args.real:
+        from repro.launch import compile_cache
         from repro.serve.session import (SessionConfig, StreamingSession,
                                          cap_specs)
+        compile_cache.enable()
 
         # multi-lane demo defaults: enough streams that each lane's
         # queue exceeds the micro-batch (genuinely WAITING streams are
@@ -188,13 +205,13 @@ def main() -> None:
         session = StreamingSession(SessionConfig(
             executor="batched" if args.batched else "sequential",
             models=model_list or None,
+            published_widths=args.published_widths,
             max_batch=args.max_batch
             or (3 if args.lanes > 1 else 4),
             lanes=args.lanes,
             workers_per_node=args.workers_per_node,
             budget_factor=budget_factor,
-            # 0 -> everyone fits (per lane), like the legacy default
-            pool_streams=args.pool_streams or n_streams + 1,
+            pool_streams=args.pool_streams or None,
             context_backend=args.context_backend,
             arrival_scale=args.arrival_scale,
             front_door=fd_cfg,

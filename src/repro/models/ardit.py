@@ -66,6 +66,18 @@ def chunk_tokens(cfg: ModelConfig) -> int:
     return cfg.ardit_chunk_frames * cfg.ardit_frame_tokens
 
 
+# page sizes are rounded up to whole 128-token lane rows, so the TPU
+# paged kernel tiles a page's context into (8,128)-legal blocks
+PAGE_ALIGN = 128
+
+
+def page_tokens(cfg: ModelConfig) -> int:
+    """Tokens per KV-pool page: room for the cond sink or one chunk,
+    rounded up to ``PAGE_ALIGN`` (the tail is never valid)."""
+    t = max(COND_TOKENS, chunk_tokens(cfg))
+    return -(-t // PAGE_ALIGN) * PAGE_ALIGN
+
+
 def cache_capacity(cfg: ModelConfig) -> int:
     """KV capacity in tokens: cond sink + window chunks."""
     return COND_TOKENS + cfg.ardit_window_chunks * chunk_tokens(cfg)
@@ -75,19 +87,26 @@ def cache_capacity(cfg: ModelConfig) -> int:
 # init
 # ---------------------------------------------------------------------------
 
-def _init_layer(cfg: ModelConfig, key, dtype) -> Params:
+def _init_layer(cfg: ModelConfig, key, dtype, open_gates: bool) -> Params:
     ks = L.split_keys(key, 3)
     d = cfg.d_model
     return {
         "attn": L.init_attn(cfg, ks[0], dtype),
         "mlp": L.init_mlp(cfg, ks[1], dtype),
-        # adaLN-zero: 6 modulation vectors per layer
-        "mod": jnp.zeros((d, 6 * d), dtype),
+        # adaLN(-zero): 6 modulation vectors per layer
+        "mod": (L.dense_init(ks[2], (d, 6 * d), dtype) if open_gates
+                else jnp.zeros((d, 6 * d), dtype)),
         "mod_b": jnp.zeros((6 * d,), dtype),
     }
 
 
-def init_params(cfg: ModelConfig, key) -> Params:
+def init_params(cfg: ModelConfig, key, open_gates: bool = False) -> Params:
+    """Random weights.  adaLN-zero by default (the DiT training init: every
+    residual branch starts gated off).  ``open_gates=True`` draws the
+    modulation projections like every other dense layer instead, so the
+    residual branches — attention over the KV context among them —
+    shape the output: what a serving check on random weights needs, or
+    any comparison of attention paths would hold vacuously."""
     dtype = jnp.dtype(cfg.param_dtype)
     ks = L.split_keys(key, 6)
     layer_keys = jax.random.split(ks[0], cfg.n_layers)
@@ -97,9 +116,12 @@ def init_params(cfg: ModelConfig, key) -> Params:
         "cond_proj": L.dense_init(ks[2], (d, d), dtype),
         "t_mlp1": L.dense_init(ks[3], (256, d), dtype),
         "t_mlp2": L.dense_init(ks[4], (d, d), dtype),
-        "layers": jax.vmap(lambda k: _init_layer(cfg, k, dtype))(layer_keys),
+        "layers": jax.vmap(lambda k: _init_layer(cfg, k, dtype,
+                                                 open_gates))(layer_keys),
         "final_norm": jnp.ones((d,), dtype),
-        "final_mod": jnp.zeros((d, 2 * d), dtype),
+        "final_mod": (L.dense_init(jax.random.fold_in(key, 6),
+                                   (d, 2 * d), dtype) if open_gates
+                      else jnp.zeros((d, 2 * d), dtype)),
         "out_proj": L.dense_init(ks[5], (d, LATENT_CH), dtype, scale=0.02),
     }
 
@@ -226,13 +248,10 @@ def chunk_forward(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
 # serving: host-side cache bookkeeping + chunk generation
 # ---------------------------------------------------------------------------
 
-def init_cache(cfg: ModelConfig, p: Params, cond: jax.Array,
-               kv_dtype: Optional[str] = None) -> Dict[str, Any]:
-    """Cache whose sink slot is the conditioning tokens.
-
-    cond: [B, COND_TOKENS, d_model] (stub text-encoder output).
-    ``len``/``chunks`` are host-side Python ints (static shapes per state).
-    """
+def cond_kv(cfg: ModelConfig, p: Params, cond: jax.Array,
+            kv_dtype: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """The sink KV of the conditioning tokens, every layer:
+    cond [B, COND_TOKENS, d_model] -> k, v [L, B, COND_TOKENS, Hkv, Dh]."""
     dt = jnp.dtype(kv_dtype or cfg.kv_dtype)
     cond = cond.astype(p["cond_proj"].dtype) @ p["cond_proj"]
     positions = jnp.arange(COND_TOKENS)
@@ -241,9 +260,19 @@ def init_cache(cfg: ModelConfig, p: Params, cond: jax.Array,
         _, k, v = L.attn_qkv(cfg, lp, cond, positions)
         return k, v
 
-    ks, vs = jax.vmap(kv_of)(p["layers"]["attn"])   # [L,B,T,H,Dh]
-    return {"k": ks.astype(dt), "v": vs.astype(dt),
-            "len": COND_TOKENS, "chunks": 0}
+    ks, vs = jax.vmap(kv_of)(p["layers"]["attn"])
+    return ks.astype(dt), vs.astype(dt)
+
+
+def init_cache(cfg: ModelConfig, p: Params, cond: jax.Array,
+               kv_dtype: Optional[str] = None) -> Dict[str, Any]:
+    """Cache whose sink slot is the conditioning tokens.
+
+    cond: [B, COND_TOKENS, d_model] (stub text-encoder output).
+    ``len``/``chunks`` are host-side Python ints (static shapes per state).
+    """
+    ks, vs = cond_kv(cfg, p, cond, kv_dtype)
+    return {"k": ks, "v": vs, "len": COND_TOKENS, "chunks": 0}
 
 
 def visible_context(cfg: ModelConfig, cache: Dict[str, Any],
@@ -333,13 +362,15 @@ def _chunk_forward_pages(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
     """Shared DiT body of the page-table-native forwards.
 
     ``pools`` is a tuple of ``(k_pages, v_pages, block_table, head_lo,
-    head_hi)`` KV-head shards covering ``[0, n_kv_heads)``: one shard
-    is the plain paged forward (no head slicing at all — identical to
-    the pre-SP code path); two shards is elastic SP2, each shard's
-    attention reading its own pool/table (Ulysses head partition —
-    per-head attention never mixes heads, so the sharded result is
-    bit-identical to the single-shard one whenever the shards mirror
-    the same KV).
+    head_hi)`` KV-head shards covering ``[0, n_kv_heads)``, each pool
+    the whole layer-stacked head-major [L, n_pages, Hkv, page, Dh]
+    buffer.  One shard is the plain paged forward: the layer loop hands
+    attention the layer INDEX and the kernel reads that layer of the
+    pool in place (no per-layer pool slice is ever copied).  Two shards
+    is elastic SP2, each shard's attention reading its own pool/table
+    (Ulysses head partition — per-head attention never mixes heads, so
+    the sharded result is bit-identical to the single-shard one
+    whenever the shards mirror the same KV).
     """
     b, tc, _ = x_chunk.shape
     d = cfg.d_model
@@ -356,21 +387,20 @@ def _chunk_forward_pages(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
     ones = jnp.ones((d,), h.dtype)
 
     def body(hh, xs):
-        lp = xs["layer"]
+        lp, li = xs["layer"], xs["index"]
         mod = jax.nn.silu(temb) @ lp["mod"] + lp["mod_b"]         # [B,6D]
         sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
         a_in = _modulate(L.rmsnorm(hh, ones, cfg.norm_eps), sh1, sc1)
         q, k, v = L.attn_qkv(cfg, lp["attn"], a_in, positions)
         outs = []
-        for i, (_, _, tbl, lo, hi) in enumerate(pools):
-            kp, vp = xs[f"kp{i}"], xs[f"vp{i}"]
+        for kp, vp, tbl, lo, hi in pools:
             if single:
-                o_s = paged_mha(q, kp, vp, tbl, page_mask, k, v,
+                o_s = paged_mha(q, kp, vp, tbl, page_mask, k, v, li,
                                 n_kv_heads=hkv, sink=COND_TOKENS,
                                 chunk_tokens=tc)
             else:
                 o_s = paged_mha(shard_heads(q, hkv, lo, hi),
-                                kp[..., lo:hi, :], vp[..., lo:hi, :],
+                                kp[li, :, lo:hi], vp[li, :, lo:hi],
                                 tbl, page_mask,
                                 shard_heads(k, hkv, lo, hi),
                                 shard_heads(v, hkv, lo, hi),
@@ -386,9 +416,7 @@ def _chunk_forward_pages(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
         hh = hh + g2[:, None, :] * L.mlp_block(cfg, lp["mlp"], f_in)
         return hh, {"k": k, "v": v}
 
-    xs = {"layer": p["layers"]}
-    for i, (kp, vp, _, _, _) in enumerate(pools):
-        xs[f"kp{i}"], xs[f"vp{i}"] = kp, vp
+    xs = {"layer": p["layers"], "index": jnp.arange(cfg.n_layers)}
     h, new_kv = jax.lax.scan(body, h, xs)
 
     mod = jax.nn.silu(temb) @ p["final_mod"]
@@ -405,9 +433,9 @@ def chunk_forward_paged(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
     """``chunk_forward`` with the cached context consumed IN PLACE from
     the paged KV pool instead of a gathered [L, B, ctx_len, ...] copy.
 
-    k_pages/v_pages [L, n_pages, page, Hkv, Dh] — the whole device
-    pool; block_table [B, n] per-stream page tables (entry 0 = sink
-    page, entry 1+r = ring slot r); page_mask [B, n*page] visible
+    k_pages/v_pages [L, n_pages, Hkv, page, Dh] — the whole head-major
+    device pool; block_table [B, n] per-stream page tables (entry 0 =
+    sink page, entry 1+r = ring slot r); page_mask [B, n*page] visible
     context tokens in table order, or None when every valid token is
     visible (homogeneous fill, full window, no sparsity — per-score
     masking is skipped entirely, like the gathered path's dropped
@@ -563,19 +591,10 @@ def init_batched_cache(cfg: ModelConfig, p: Params, cond: jax.Array,
     {"k","v"} of [L, B, cap, Hkv, Dh] plus host-side per-stream chunk
     counts ``chunks`` [B].
     """
-    dt = jnp.dtype(kv_dtype or cfg.kv_dtype)
-    cond = cond.astype(p["cond_proj"].dtype) @ p["cond_proj"]
-    positions = jnp.arange(COND_TOKENS)
-
-    def kv_of(lp):
-        _, k, v = L.attn_qkv(cfg, lp, cond, positions)
-        return k, v
-
-    ks, vs = jax.vmap(kv_of)(p["layers"]["attn"])   # [L,B,COND,H,Dh]
+    ks, vs = cond_kv(cfg, p, cond, kv_dtype)
     pad = ((0, 0), (0, 0), (0, cache_capacity(cfg) - COND_TOKENS),
            (0, 0), (0, 0))
-    return {"k": jnp.pad(ks.astype(dt), pad),
-            "v": jnp.pad(vs.astype(dt), pad),
+    return {"k": jnp.pad(ks, pad), "v": jnp.pad(vs, pad),
             "chunks": np.zeros(cond.shape[0], np.int64)}
 
 

@@ -252,14 +252,16 @@ def merge_head_shards(outs: Sequence[jax.Array],
 
 def paged_mha(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
               block_table: jax.Array, page_mask: jax.Array,
-              chunk_k: jax.Array, chunk_v: jax.Array, *,
+              chunk_k: jax.Array, chunk_v: jax.Array, layer=None, *,
               n_kv_heads: int, sink: int = 0,
               chunk_tokens: int = 0) -> jax.Array:
     """Page-table-native attention for chunk-wise generation.
 
     q [B,Sq,Hq,D] attends to (a) the visible cached context, read IN
-    PLACE from the physical page pool ``k_pages``/``v_pages``
-    [n_pages, page, Hkv, D] through per-stream ``block_table`` [B, n]
+    PLACE from the physical head-major page pool ``k_pages``/``v_pages``
+    [n_pages, Hkv, page, D] (or the layer-stacked [L, n_pages, Hkv,
+    page, D] pool, with ``layer`` picking the layer without slicing it)
+    through per-stream ``block_table`` [B, n]
     with ``page_mask`` [B, n*page] marking the visible context tokens in
     table order (ring residency + fidelity window + sparsity + page-tail
     validity + partial-window page drops baked in by the caller — all
@@ -281,7 +283,7 @@ def paged_mha(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     b, sq, hq, d = q.shape
     scale = 1.0 / math.sqrt(d)
     ctx = paged_chunk_attention(q, k_pages, v_pages, block_table,
-                                page_mask, sink=sink,
+                                page_mask, layer, sink=sink,
                                 chunk_tokens=chunk_tokens)
     own = _segment_attn(_group(q, n_kv_heads), chunk_k, chunk_v, None,
                         scale)

@@ -75,12 +75,16 @@ def write_block_layers(cache: jax.Array, new: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# page-granular pool (serve/batcher.py KVPool): KV lives as
-# [L, n_pages, page_tokens, ...] and each stream owns a page *table*
-# (entry 0 = cond sink page, entry 1+r = ring slot r, chunk c in entry
-# 1 + c % window_chunks).  The helpers below are pure permutations of
-# pool rows, so a page-table cache is bitwise-identical to the stacked
-# per-stream chunk-ring layout it replaces.
+# page-granular pool (serve/batcher.py KVPool): KV lives HEAD-MAJOR as
+# [L, n_pages, Hkv, page_tokens, Dh] — each (page, head) is one
+# contiguous [page_tokens, Dh] slab, which is the block the TPU paged
+# kernel DMAs — and each stream owns a page *table* (entry 0 = cond sink
+# page, entry 1+r = ring slot r, chunk c in entry 1 + c % window_chunks).
+# The model produces KV token-major [L, b, T, Hkv, Dh]; ``to_pages``
+# turns a chunk into page layout once, at append time.  The helpers
+# below are pure permutations of pool rows, so a page-table cache is
+# bitwise-identical to the stacked per-stream chunk-ring layout it
+# replaces.
 # ---------------------------------------------------------------------------
 
 
@@ -95,11 +99,17 @@ def page_of_chunk(chunk_idx: int, window_chunks: int) -> int:
     return 1 + chunk_idx % window_chunks
 
 
+def to_pages(kv: jax.Array) -> jax.Array:
+    """Token-major model KV [L, b, T, Hkv, Dh] -> page layout
+    [L, b, Hkv, T, Dh] (what ``pool_write_pages`` takes)."""
+    return jnp.swapaxes(kv, 2, 3)
+
+
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def gather_pages(pool: jax.Array, tables: jax.Array, sink: int,
                  chunk_tokens: int, n_ring: int) -> jax.Array:
-    """pool [L,n_pages,P,...]; tables [b, 1+W] page ids ->
-    [L, b, sink + n_ring*chunk_tokens, ...].
+    """pool [L,n_pages,Hkv,P,Dh]; tables [b, 1+W] page ids ->
+    token-major [L, b, sink + n_ring*chunk_tokens, Hkv, Dh].
 
     Reassembles, per stream, the contiguous sink+ring context the
     stacked chunk-ring layout kept per row: tokens [0, sink) from the
@@ -107,12 +117,14 @@ def gather_pages(pool: jax.Array, tables: jax.Array, sink: int,
     [sink + r*chunk_tokens, sink + (r+1)*chunk_tokens) from table entry
     1+r, sliced to the first ``n_ring`` ring slots (the sub-batch's
     resident extent).  A pure gather: bitwise-exact."""
-    sink_part = pool[:, tables[:, 0], :sink]
+    l, b = pool.shape[0], tables.shape[0]
+    hkv, d = pool.shape[2], pool.shape[4]
+    sink_part = jnp.swapaxes(pool[:, tables[:, 0], :, :sink], 2, 3)
     if n_ring == 0:
         return sink_part
-    ring = pool[:, tables[:, 1:1 + n_ring], :chunk_tokens]
-    l, b = ring.shape[:2]
-    ring = ring.reshape((l, b, n_ring * chunk_tokens) + ring.shape[4:])
+    ring = pool[:, tables[:, 1:1 + n_ring], :, :chunk_tokens]
+    ring = ring.transpose(0, 1, 2, 4, 3, 5).reshape(
+        l, b, n_ring * chunk_tokens, hkv, d)
     return jnp.concatenate([sink_part, ring], axis=2)
 
 
@@ -135,41 +147,28 @@ def mask_to_pages(mask: np.ndarray, n_ring: int, sink: int,
     return out
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
 def pool_write_pages(pool: jax.Array, new: jax.Array,
-                     pages: jax.Array) -> jax.Array:
-    """pool [L,n_pages,P,...]; new [L,b,T,...] (T <= P); pages [b].
+                     pages: jax.Array, head_offset: int = 0) -> jax.Array:
+    """pool [L,n_pages,Hkv,P,Dh]; new [L,b,h,T,Dh] in page layout
+    (T <= P, h <= Hkv - head_offset); pages [b].
 
     Writes one T-token block per stream at token 0 of its destination
-    page — the page-granular sibling of ``write_block``.  The pool
-    buffer is donated so the update happens in place where the backend
-    supports it.  Device-backed pools rely on this donation staying
-    device-local: ``new`` blocks arriving from another lane (migration
-    landings, SP shipbacks) are ``device_put`` onto the pool's device by
-    the caller BEFORE this jit, so the write never silently pins the
-    donated pool to a foreign device."""
+    page, KV heads [head_offset, head_offset + h) — the page-granular
+    sibling of ``write_block``.  ``head_offset`` > 0 serves the
+    elastic-SP donor pool, which holds only the upper half of a
+    stream's KV heads (Ulysses head partition, paper App. C.4), so its
+    appends touch only that half.  The pool buffer is donated so the
+    update happens in place where the backend supports it.
+    Device-backed pools rely on this donation staying device-local:
+    ``new`` blocks arriving from another lane (migration landings, SP
+    shipbacks) are ``device_put`` onto the pool's device by the caller
+    BEFORE this jit, so the write never silently pins the donated pool
+    to a foreign device."""
     for i in range(new.shape[1]):
         pool = jax.lax.dynamic_update_slice(
             pool, new[:, i:i + 1].astype(pool.dtype),
-            (0, pages[i], 0) + (0,) * (pool.ndim - 3))
-    return pool
-
-
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
-def pool_write_pages_heads(pool: jax.Array, new: jax.Array,
-                           pages: jax.Array, head_offset: int) -> jax.Array:
-    """pool [L,n_pages,P,Hkv,D]; new [L,b,T,h_sub,D] (T <= P,
-    h_sub <= Hkv - head_offset); pages [b].
-
-    Head-sliced sibling of ``pool_write_pages``: writes each block at
-    token 0 of its destination page, KV-head offset ``head_offset`` —
-    the elastic-SP donor pool holds only its half of a stream's KV
-    heads (Ulysses head partition, paper App. C.4), so appends touch
-    only that half."""
-    for i in range(new.shape[1]):
-        pool = jax.lax.dynamic_update_slice(
-            pool, new[:, i:i + 1].astype(pool.dtype),
-            (0, pages[i], 0, head_offset, 0))
+            (0, pages[i], head_offset, 0, 0))
     return pool
 
 

@@ -27,12 +27,14 @@ stay honest (re-profiling).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 from repro.core import queues
@@ -281,7 +283,9 @@ class KVPool:
     """Page-granular device KV pool (the ROADMAP "paged-KV
     defragmentation" item).
 
-    KV lives as one [L, n_pages, page_tokens, Hkv, Dh] pair; a resident
+    KV lives as one head-major [L, n_pages, Hkv, page_tokens, Dh] pair
+    (``kvcache`` page layout: one contiguous [page_tokens, Dh] slab per
+    page and head, the TPU paged kernel's DMA block); a resident
     stream owns ``1 + window_chunks`` pages recorded in its page table
     (cond sink page + ring of chunk pages; chunk c lands in table entry
     ``1 + c % window_chunks``).  Sub-batches assemble their contiguous
@@ -302,22 +306,24 @@ class KVPool:
         self.cfg, self.params = cfg, params
         self._tc = A.chunk_tokens(cfg)
         self._w = cfg.ardit_window_chunks
-        self.page_tokens = max(A.COND_TOKENS, self._tc)
+        self.page_tokens = A.page_tokens(cfg)
         pps = kvcache.pages_per_stream(self._w)
         self.ledger = PageLedger(max_streams * pps, pps)
-        shape = (cfg.n_layers, self.ledger.n_pages, self.page_tokens,
-                 cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_layers, self.ledger.n_pages, cfg.n_kv_heads,
+                 self.page_tokens, cfg.head_dim)
         dt = jnp.dtype(cfg.kv_dtype)
         # a device-backed pool COMMITS its buffers to its lane's device
         # (``jax.devices()[lane]`` under a multi-device runtime), so a
         # cross-lane page move is a real ``jax.device_put`` between
-        # device buffers, not a host-array relabel
+        # device buffers, not a host-array relabel.  A program placed on
+        # that device fills them there: ``jnp.zeros(..., device=)`` fills
+        # on the default device and copies, so every lane's pool would
+        # pass through device 0's memory
         self.device = device
-        self.k = jnp.zeros(shape, dt)
-        self.v = jnp.zeros(shape, dt)
-        if device is not None:
-            self.k = jax.device_put(self.k, device)
-            self.v = jax.device_put(self.v, device)
+        zeros = jax.jit(functools.partial(jnp.zeros, shape, dt),
+                        out_shardings=(None if device is None else
+                                       SingleDeviceSharding(device)))
+        self.k, self.v = zeros(), zeros()
         self._spill: Dict[int, Dict[str, Any]] = {}   # sid -> host pages
         # device-side per-stream page tables, built once per residency
         # epoch (invalidated on admit/evict/restore/retire) instead of
@@ -378,6 +384,8 @@ class KVPool:
     # ---- device writes / gathers -------------------------------------------
     def _write(self, pages: np.ndarray, nk: jax.Array,
                nv: jax.Array) -> None:
+        """Write page-layout blocks [L, b, Hkv, T, Dh] at token 0 of
+        ``pages``."""
         pg = jnp.asarray(np.asarray(pages), jnp.int32)
         if self.device is not None:
             # incoming rows may be committed to ANOTHER lane's device
@@ -391,9 +399,9 @@ class KVPool:
         self.v = kvcache.pool_write_pages(self.v, nv, pg)
 
     def _sink_kv(self, cond: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        sub = A.init_batched_cache(self.cfg, self.params, cond)
-        return (sub["k"][:, :, :A.COND_TOKENS],
-                sub["v"][:, :, :A.COND_TOKENS])
+        """The cond (sink) KV in page layout [L, 1, Hkv, COND, Dh]."""
+        k, v = A.cond_kv(self.cfg, self.params, cond)
+        return kvcache.to_pages(k), kvcache.to_pages(v)
 
     def table_rows(self, sid: int) -> np.ndarray:
         """Physical page rows of ``sid``'s table with holes (-1:
@@ -446,11 +454,11 @@ class KVPool:
             self._write(table[:1], sk, sv)
             return True
         dt = self.k.dtype
-        pages = np.zeros((self.cfg.n_layers, self.pages_per_stream,
-                          self.page_tokens) + self.k.shape[3:], dt)
+        pages = np.zeros((self.cfg.n_layers, self.pages_per_stream)
+                         + self.k.shape[2:], dt)
         pages_v = np.zeros_like(pages)
-        pages[:, 0, :A.COND_TOKENS] = np.asarray(sk[:, 0].astype(dt))
-        pages_v[:, 0, :A.COND_TOKENS] = np.asarray(sv[:, 0].astype(dt))
+        pages[:, 0, :, :A.COND_TOKENS] = np.asarray(sk[:, 0].astype(dt))
+        pages_v[:, 0, :, :A.COND_TOKENS] = np.asarray(sv[:, 0].astype(dt))
         self._spill[sid] = {"k": pages, "v": pages_v}
         self.ledger.spilled.add(sid)
         self.ledger.chunks[sid] = 0
@@ -620,7 +628,8 @@ class KVPool:
             if np.any(np.asarray(self.ledger.tables[sid]) < 0):
                 self._dev_tables.pop(sid, None)
         pages = np.asarray([self.ledger.append_page(sid) for sid in sids])
-        self._write(pages, new_kv["k"], new_kv["v"])
+        self._write(pages, kvcache.to_pages(new_kv["k"]),
+                    kvcache.to_pages(new_kv["v"]))
         for sid in sids:
             self.ledger.chunks[sid] += 1
             self.ledger.prune_dropped(sid)
@@ -1366,15 +1375,14 @@ class BatchedChunkExecutor(ChunkExecutor):
         pool's page set for ``sid`` (kept in lockstep with the home
         pool's full-head append)."""
         h2 = self.cfg.n_kv_heads // 2
-        nk, nv = new_kv["k"][..., h2:, :], new_kv["v"][..., h2:, :]
+        nk = kvcache.to_pages(new_kv["k"])[:, :, h2:]
+        nv = kvcache.to_pages(new_kv["v"])[:, :, h2:]
         if quant == "fp8":
             nk = nk.astype(jnp.float8_e4m3fn)
             nv = nv.astype(jnp.float8_e4m3fn)
         page = jnp.asarray([link.pool.ledger.append_page(sid)], jnp.int32)
-        link.pool.k = kvcache.pool_write_pages_heads(
-            link.pool.k, nk, page, h2)
-        link.pool.v = kvcache.pool_write_pages_heads(
-            link.pool.v, nv, page, h2)
+        link.pool.k = kvcache.pool_write_pages(link.pool.k, nk, page, h2)
+        link.pool.v = kvcache.pool_write_pages(link.pool.v, nv, page, h2)
         link.pool.ledger.chunks[sid] += 1
 
     def remaining_estimate(self, sid: int) -> float:

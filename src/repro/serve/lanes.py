@@ -448,10 +448,10 @@ class LanePool:
         # keep them unread on both pools
         rows = jnp.asarray(home.table_rows(sid), jnp.int32)
         drows = jnp.asarray(dpool.ledger.tables[sid], jnp.int32)
-        kh = home.k[:, rows][..., h2:, :]       # [L, pps, P, H/2, Dh]
-        vh = home.v[:, rows][..., h2:, :]
-        dpool.k = kvcache.pool_write_pages_heads(dpool.k, kh, drows, h2)
-        dpool.v = kvcache.pool_write_pages_heads(dpool.v, vh, drows, h2)
+        kh = home.k[:, rows][:, :, h2:]         # [L, pps, H/2, P, Dh]
+        vh = home.v[:, rows][:, :, h2:]
+        dpool.k = kvcache.pool_write_pages(dpool.k, kh, drows, h2)
+        dpool.v = kvcache.pool_write_pages(dpool.v, vh, drows, h2)
         return kh.nbytes + vh.nbytes
 
     def _copy_sp_full(self, home: KVPool, dpool: KVPool,

@@ -29,6 +29,16 @@ from repro.models import kvcache
 from repro.models.registry import ModelAPI, get_api
 from repro.profiler.profiles import MODEL_COST, ModelProfile, get_profile
 
+# share of a device's memory kept back from the KV pools when they are
+# sized (``fit_pool_streams``): compiled step programs, their
+# activations and the per-chunk KV outputs live there.  At published
+# widths on a 16 GB chip one fused step of two streams needs ~1.6 GiB
+# beyond its arguments (v5e compile of ``denoise_step_paged``).
+ACTIVATION_HEADROOM = 0.25
+# co-resident streams per pool when the memory does not bound them (or
+# the backend reports no memory limit, as the host CPU does)
+MAX_POOL_STREAMS = 16
+
 # Registry arch id -> profile surface name.  The analytic profile is keyed
 # by the paper's model columns; registry ids not listed here use their own
 # name (falling through to the default quality ceiling in ``Q_MAX`` and
@@ -75,9 +85,9 @@ class ModelBundle:
 
     @property
     def page_bytes(self) -> int:
-        """KV bytes of one page of this bundle's pool."""
+        """KV bytes of one page of this bundle's pool, K and V."""
         itemsize = np.dtype(self.kv_dtype).itemsize
-        return (self.cfg.n_layers * self.page_tokens
+        return (2 * self.cfg.n_layers * self.page_tokens
                 * self.cfg.n_kv_heads * self.cfg.head_dim * itemsize)
 
     @property
@@ -87,19 +97,23 @@ class ModelBundle:
 
 
 def _pool_geometry(cfg: ModelConfig):
-    page_tokens = max(A.COND_TOKENS, A.chunk_tokens(cfg))
+    page_tokens = A.page_tokens(cfg)
     pps = kvcache.pages_per_stream(cfg.ardit_window_chunks)
     return page_tokens, pps
 
 
-def resolve_bundle(model: Union[str, ModelConfig], *, seed: int = 0,
-                   reduced: bool = True, step_cache: bool = False,
+def resolve_bundle(model: Union[str, ModelConfig, ModelBundle], *,
+                   seed: int = 0, reduced: bool = True,
+                   step_cache: bool = False,
                    params: Any = None) -> ModelBundle:
-    """Resolve one registry arch (or explicit config) into a bundle.
+    """Resolve one registry arch (or explicit config) into a bundle; a
+    ``ModelBundle`` (e.g. with weights the caller made) passes through.
 
     Live serving drives the AR-DiT denoise path, so the config must be
     ``family == "ardit"``; other registry families are co-served
     analytically in the simulator (per-model cost priors) only."""
+    if isinstance(model, ModelBundle):
+        return model
     if isinstance(model, str):
         cfg = get_config(model)
         if reduced:
@@ -128,7 +142,36 @@ def resolve_bundle(model: Union[str, ModelConfig], *, seed: int = 0,
         step_cost=MODEL_COST.get(pname, 1.0))
 
 
-def resolve_bundles(models: Sequence[Union[str, ModelConfig]], *,
+def fit_pool_streams(bundles: Sequence[ModelBundle],
+                     lanes: int = 1) -> int:
+    """Co-resident streams per lane (``KVPool`` ``max_streams``) that
+    fit the device: the memory left after what is already in use (the
+    bundles' weights) and ``ACTIVATION_HEADROOM``, over the KV bytes of
+    one stream of every co-served bundle (each bundle's pool holds that
+    many streams) times the lanes that share a device.  Capped at
+    ``MAX_POOL_STREAMS``, which is also the answer on a backend that
+    reports no memory limit (the host CPU).  Raises when not even one
+    stream fits.
+    """
+    import jax
+    devs = jax.devices()
+    stats = devs[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return MAX_POOL_STREAMS
+    per_lane = sum(b.stream_bytes for b in bundles)
+    sharing = -(-lanes // len(devs))          # lanes per device
+    free = (stats["bytes_limit"] * (1.0 - ACTIVATION_HEADROOM)
+            - stats.get("bytes_in_use", 0))
+    fit = int(free // (per_lane * sharing))
+    if fit < 1:
+        raise ValueError(
+            f"no stream fits {devs[0].device_kind}: {free / 2**30:.2f} GiB "
+            f"free for KV, {per_lane * sharing / 2**30:.2f} GiB per stream")
+    return min(MAX_POOL_STREAMS, fit)
+
+
+def resolve_bundles(models: Sequence[Union[str, ModelConfig, ModelBundle]],
+                    *,
                     seed: int = 0, reduced: bool = True,
                     step_cache: bool = False) -> List[ModelBundle]:
     """Resolve a co-served model set; weights are normalized so the FIRST

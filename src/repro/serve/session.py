@@ -72,6 +72,8 @@ from repro.sched_sim.frontdoor import FrontDoor, FrontDoorConfig
 from repro.sched_sim.workloads import StreamSpec
 from repro.serve.executor import ServedStream
 from repro.serve.lanes import LanePool
+from repro.serve.modelplane import (MAX_POOL_STREAMS, fit_pool_streams,
+                                    resolve_bundles)
 
 
 @dataclasses.dataclass
@@ -85,7 +87,10 @@ class SessionConfig:
     re-enables re-homing and elastic SP in the control plane);
     ``workers_per_node`` groups lanes into nodes for the intra-node
     preferences of Algorithm 1 and SS4.3 (0 = all lanes in one node).
-    ``pool_streams`` caps co-resident streams PER LANE.
+    ``pool_streams`` caps co-resident streams PER LANE; None sizes
+    each pool to the device memory (``modelplane.fit_pool_streams``,
+    from the bundles' per-stream KV bytes) for ``models`` sessions, and
+    to ``MAX_POOL_STREAMS`` otherwise.
     ``tick_interval`` is the control-tick cadence in session seconds; 0
     runs Algorithm 2 at every scheduler iteration (the natural cadence
     when chunk latencies are far below the paper's 3 s tick).
@@ -94,7 +99,11 @@ class SessionConfig:
     and tests don't wait out real Poisson gaps.  ``realtime_budget``
     fixes the playout seconds per chunk; None calibrates 4x the
     measured top-fidelity latency so any host speed exercises both BMPR
-    modes.
+    modes — except at published widths, where it is the real playout
+    cadence (``cost_model.CHUNK_SECONDS``, 0.75 s).
+    ``published_widths`` resolves ``models`` at the registry's published
+    widths (the chip path) instead of ``cfg.reduced()`` (CPU tests and
+    demos).
     """
     executor: str = "batched"
     max_batch: int = 4
@@ -128,6 +137,7 @@ class SessionConfig:
     # takes the exact legacy single-model path; ``models`` and
     # ``model_cfg`` are mutually exclusive.
     models: Optional[List[Any]] = None
+    published_widths: bool = False
     realtime_budget: Optional[float] = None
     budget_factor: float = 4.0     # chunk_seconds = factor x top latency
     tick_interval: float = 0.0
@@ -328,16 +338,21 @@ class StreamingSession:
         self.cfg = config or SessionConfig()
         n_lanes = max(1, self.cfg.lanes)
         self.bundles = None
+        assert self.cfg.models or not self.cfg.published_widths, \
+            "published_widths resolves SessionConfig.models"
         if self.cfg.models:
             assert executor is None and self.cfg.model_cfg is None, \
                 "SessionConfig.models is incompatible with executor= " \
                 "and model_cfg"
             assert self.cfg.executor == "batched", \
                 "co-serving rides the batched paged executor"
-            from repro.serve.modelplane import resolve_bundles
             self.bundles = resolve_bundles(
                 self.cfg.models, seed=self.cfg.seed,
+                reduced=not self.cfg.published_widths,
                 step_cache=self.cfg.step_cache)
+        pool_streams = self.cfg.pool_streams or (
+            fit_pool_streams(self.bundles, lanes=n_lanes)
+            if self.bundles is not None else MAX_POOL_STREAMS)
         if executor is not None:
             assert n_lanes == 1, \
                 "multi-lane sessions build their own executors " \
@@ -346,19 +361,19 @@ class StreamingSession:
         elif self.cfg.executor == "sequential":
             assert n_lanes == 1, "the sequential executor is single-lane"
             from repro.serve.executor import SequentialChunkExecutor
-            self.lanes = LanePool.wrap(
-                SequentialChunkExecutor(seed=self.cfg.seed))
+            self.lanes = LanePool.wrap(SequentialChunkExecutor(
+                cfg=self.cfg.model_cfg, seed=self.cfg.seed))
         elif self.bundles is not None:
             self.lanes = LanePool(
                 n_lanes, seed=self.cfg.seed,
-                max_streams=self.cfg.pool_streams or 16,
+                max_streams=pool_streams,
                 context_backend=self.cfg.context_backend,
                 page_evict=self.cfg.page_evict,
                 bundles=self.bundles)
         else:
             self.lanes = LanePool(
                 n_lanes, cfg=self.cfg.model_cfg, seed=self.cfg.seed,
-                max_streams=self.cfg.pool_streams or 16,
+                max_streams=pool_streams,
                 context_backend=self.cfg.context_backend,
                 page_evict=self.cfg.page_evict)
         self.executor = self.lanes.ex(0)      # back-compat accessor
@@ -400,7 +415,9 @@ class StreamingSession:
             lex.latency_ema[HIGHEST_QUALITY.key] = self.top_latency
             if hasattr(lex, "step_ema"):
                 lex.step_ema[HIGHEST_QUALITY.key] = step
-        self.chunk_seconds = (self.cfg.realtime_budget
+        cadence = self.cfg.realtime_budget or (
+            cm.CHUNK_SECONDS if self.cfg.published_widths else None)
+        self.chunk_seconds = (cadence
                               or self.cfg.budget_factor * self.top_latency)
         time_scale = (self._profile.latency(HIGHEST_QUALITY)
                       / max(self.top_latency, 1e-9))
@@ -430,7 +447,7 @@ class StreamingSession:
             # one session playout cadence, sized so the SLOWEST model's
             # top-fidelity chunk fits the same budget-factor headroom
             self.chunk_seconds = (
-                self.cfg.realtime_budget
+                cadence
                 or self.cfg.budget_factor
                 * max(b.top_latency for b in self.bundles))
         multi = self.lanes.n_lanes > 1

@@ -139,8 +139,8 @@ class TestPagedChunkAttention:
             paged_chunk_attention_ref
         ks = jax.random.split(KEY, 5)
         q = jax.random.normal(ks[0], (B, Sq, Hq, D))
-        kp = jax.random.normal(ks[1], (ptot, page, Hkv, D))
-        vp = jax.random.normal(ks[2], (ptot, page, Hkv, D))
+        kp = jax.random.normal(ks[1], (ptot, Hkv, page, D))   # head-major
+        vp = jax.random.normal(ks[2], (ptot, Hkv, page, D))
         bt = jax.random.randint(ks[3], (B, npg), 0, ptot)
         mask = jax.random.uniform(ks[4], (B, npg * page)) < 0.6
         mask = mask.at[0, :page].set(False)     # a fully-masked page
@@ -150,6 +150,91 @@ class TestPagedChunkAttention:
         for g, w, name in zip(got, want, ("m", "l", "acc")):
             np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
                                        err_msg=name)
+
+    @pytest.mark.parametrize("masked", [True, False])
+    def test_tiled_layer_stacked_vs_ref(self, masked):
+        """Two query tiles (r = 1,040 rows: two 528-row tiles, the last
+        16 rows padding) and three 384-token context tiles per
+        1,152-token page, reading layer 1 of a layer-stacked pool in
+        place; the sink page's dead tiles are skipped."""
+        from repro.kernels.paged_attention.kernel import (
+            kv_tile, paged_chunk_attention_pallas, query_tile)
+        from repro.kernels.paged_attention.ref import \
+            paged_chunk_attention_ref
+        B, Sq, Hq, Hkv, D, page, npg, ptot = 2, 520, 4, 2, 8, 1152, 3, 8
+        sink, tc = 77, 1000
+        assert query_tile(Sq * Hq // Hkv) == (528, 1056)
+        assert kv_tile(page) == 384
+        ks = jax.random.split(KEY, 5)
+        q = jax.random.normal(ks[0], (B, Sq, Hq, D))
+        kp = jax.random.normal(ks[1], (2, ptot, Hkv, page, D))
+        vp = jax.random.normal(ks[2], (2, ptot, Hkv, page, D))
+        bt = jax.random.randint(ks[3], (B, npg), 0, ptot)
+        mask = None
+        if masked:
+            m = np.array(jax.random.uniform(ks[4], (B, npg, page)) < 0.6)
+            m[:, 0, sink:] = False
+            m[:, 1:, tc:] = False
+            m[1, 2] = False                     # a fully-masked page
+            mask = jnp.asarray(m.reshape(B, -1))
+        got = paged_chunk_attention_pallas(
+            q, kp, vp, bt, mask, jnp.int32(1), sink=sink, chunk_tokens=tc,
+            interpret=True)
+        want = paged_chunk_attention_ref(q, kp[1], vp[1], bt, mask,
+                                         sink=sink, chunk_tokens=tc)
+        for g, w, name in zip(got, want, ("m", "l", "acc")):
+            np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("D", [128, 96])
+    def test_published_head_shape_vs_ref(self, D):
+        """One KV head at published widths (2,640-token chunk queries,
+        2,688-token pages, head_dim 128 or 96 — the two AR-DiT
+        configs): three 880-row query tiles, three 896-token context
+        tiles per page, a sparsified mask."""
+        from repro.kernels.paged_attention.kernel import \
+            paged_chunk_attention_pallas
+        from repro.kernels.paged_attention.ref import \
+            paged_chunk_attention_ref
+        sq, page, n, sink = 2640, 2688, 2, 77
+        ks = jax.random.split(KEY, 4)
+        q = jax.random.normal(ks[0], (1, sq, 1, D), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (3, 1, page, D), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (3, 1, page, D), jnp.bfloat16)
+        bt = jnp.asarray([[2, 0]], jnp.int32)
+        m = np.array(jax.random.uniform(ks[3], (1, n, page)) < 0.5)
+        m[:, 0, sink:] = False
+        m[:, 1:, sq:] = False
+        mask = jnp.asarray(m.reshape(1, -1))
+        got = paged_chunk_attention_pallas(q, kp, vp, bt, mask, sink=sink,
+                                           chunk_tokens=sq, interpret=True)
+        want = paged_chunk_attention_ref(q, kp, vp, bt, mask, sink=sink,
+                                         chunk_tokens=sq)
+        for g, w, name in zip(got, want, ("m", "l", "acc")):
+            np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("backend,env,want", [
+    ("cpu", None, "ref"),
+    ("cpu", "1", "interpret"),
+    ("tpu", None, "pallas"),
+    ("tpu", "1", RuntimeError),
+])
+def test_kernel_mode(monkeypatch, backend, env, want):
+    """On a TPU the compiled kernel always runs (the interpret switch is
+    an error there); off it, the oracle or interpret mode."""
+    from repro.kernels import mode
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: backend)
+    if env is None:
+        monkeypatch.delenv(mode.INTERPRET_ENV, raising=False)
+    else:
+        monkeypatch.setenv(mode.INTERPRET_ENV, env)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError):
+            mode.kernel_mode()
+    else:
+        assert mode.kernel_mode() == want
 
 
 class TestFp8Matmul:

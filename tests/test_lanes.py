@@ -133,8 +133,8 @@ def test_sp2_expand_release_numerical_parity_with_sp1():
     for pool_h, pool_d in ((ex0.pool.k, lanes.ex(1).pool.k),
                            (ex0.pool.v, lanes.ex(1).pool.v)):
         np.testing.assert_array_equal(
-            np.asarray(pool_h[:, rows_h])[..., h2:, :],
-            np.asarray(pool_d[:, rows_d])[..., h2:, :])
+            np.asarray(pool_h[:, rows_h])[:, :, h2:],
+            np.asarray(pool_d[:, rows_d])[:, :, h2:])
 
     lanes.sp_release(0)
     assert lanes.sp_link(0) is None

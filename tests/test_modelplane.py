@@ -23,7 +23,7 @@ from repro.core.types import ClusterView, Stream, Worker
 from repro.sched_sim.frontdoor import FrontDoor, FrontDoorConfig
 from repro.sched_sim.metrics import summarize
 from repro.sched_sim.workloads import mixed_models, steady
-from repro.serve.batcher import compose_batch
+from repro.serve.batcher import KVPool, compose_batch
 
 FID = FidelityConfig(2, 0.0, 2, "bf16")
 MODELS = ["ardit-self-forcing", "ardit-causal-forcing"]
@@ -46,6 +46,9 @@ class TestResolveBundles:
             assert b.pages_per_stream == 1 + b.cfg.ardit_window_chunks
             assert b.page_tokens > 0 and b.page_bytes > 0
             assert b.stream_bytes == b.pages_per_stream * b.page_bytes
+            # ... and is exactly what a pool allocates per stream (K + V)
+            pool = KVPool(b.cfg, b.params, max_streams=2)
+            assert pool.k.nbytes + pool.v.nbytes == 2 * b.stream_bytes
             assert b.params is not None and b.profile is not None
         # both reduced ardit configs share geometry -> equal page cost
         assert bundles[1].page_cost == pytest.approx(1.0)

@@ -78,8 +78,9 @@ def _paged_case(seed=0, B=2, Sq=6, Hq=4, Hkv=2, D=8, n=3, page=7,
                 p_total=9):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, Sq, Hq, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(p_total, page, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(p_total, page, Hkv, D)), jnp.float32)
+    # head-major page layout [P_total, Hkv, page, D]
+    kp = jnp.asarray(rng.normal(size=(p_total, Hkv, page, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(p_total, Hkv, page, D)), jnp.float32)
     bt = jnp.asarray(rng.choice(p_total, size=(B, n), replace=False)
                      if B * n <= p_total else
                      rng.integers(0, p_total, size=(B, n)), jnp.int32)
@@ -94,9 +95,9 @@ def _paged_case(seed=0, B=2, Sq=6, Hq=4, Hkv=2, D=8, n=3, page=7,
 
 def _dense_reference(q, kp, vp, bt, mask, ck, cv, Hkv):
     b, n = bt.shape
-    _, page, _, d = kp.shape
-    kg = kp[bt.reshape(-1)].reshape(b, n * page, Hkv, d)
-    vg = vp[bt.reshape(-1)].reshape(b, n * page, Hkv, d)
+    _, _, page, d = kp.shape
+    kg = kp[bt.reshape(-1)].swapaxes(1, 2).reshape(b, n * page, Hkv, d)
+    vg = vp[bt.reshape(-1)].swapaxes(1, 2).reshape(b, n * page, Hkv, d)
     k_all = jnp.concatenate([kg, ck], axis=1)
     v_all = jnp.concatenate([vg, cv], axis=1)
     kv_mask = jnp.concatenate(
@@ -119,7 +120,7 @@ def test_ref_compact_layout_equals_full_pages():
     tails are indeed dead."""
     from repro.kernels.paged_attention.ref import paged_chunk_attention_ref
     q, kp, vp, bt, mask, _, _ = _paged_case(seed=5)
-    page = kp.shape[1]
+    page = kp.shape[2]
     sink, tc = page - 2, page - 3
     m = np.asarray(mask).copy().reshape(q.shape[0], -1, page)
     m[:, 0, sink:] = False                     # dead sink-page tail
@@ -140,7 +141,7 @@ def test_all_visible_fast_path_equals_explicit_mask():
         paged_chunk_attention_pallas
     from repro.kernels.paged_attention.ref import paged_chunk_attention_ref
     q, kp, vp, bt, _, _, _ = _paged_case(seed=9)
-    b, page, n = q.shape[0], kp.shape[1], bt.shape[1]
+    b, page, n = q.shape[0], kp.shape[2], bt.shape[1]
     sink, tc = page - 1, page - 3
     m = np.zeros((b, n, page), bool)
     m[:, 0, :sink] = True
@@ -192,8 +193,8 @@ def test_paged_chunk_kernel_shape_sweep(B, Sq, Hq, Hkv, D, n, page):
     rng = np.random.default_rng(B * 100 + n)
     p_total = max(B * n, n + 2)
     q = jnp.asarray(rng.normal(size=(B, Sq, Hq, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(p_total, page, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(p_total, page, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(p_total, Hkv, page, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(p_total, Hkv, page, D)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, p_total, size=(B, n)), jnp.int32)
     mask = jnp.asarray(rng.random((B, n * page)) < 0.6)
     got = paged_chunk_attention_pallas(q, kp, vp, bt, mask,

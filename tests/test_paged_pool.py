@@ -51,16 +51,16 @@ def full_view(pool, sids):
 def test_gather_pages_matches_manual_assembly():
     """Pure-layout check: gather_pages is the exact sink+ring
     permutation, independent of the model."""
-    L, n_pages, P, H, D = 2, 8, 7, 1, 3
+    L, n_pages, H, P, D = 2, 8, 2, 7, 3
     sink, tc = 5, 7
-    pool = jnp.asarray(
-        np.random.default_rng(0).normal(size=(L, n_pages, P, H, D)),
+    pool = jnp.asarray(       # head-major page layout
+        np.random.default_rng(0).normal(size=(L, n_pages, H, P, D)),
         jnp.float32)
     tables = np.array([[0, 3, 5], [2, 6, 1]])
     for n_ring in range(3):
         got = np.asarray(kvcache.gather_pages(
             pool, jnp.asarray(tables, jnp.int32), sink, tc, n_ring))
-        pn = np.asarray(pool)
+        pn = np.asarray(pool).swapaxes(2, 3)       # token-major pages
         for b, tab in enumerate(tables):
             parts = [pn[:, tab[0], :sink]]
             parts += [pn[:, tab[1 + r], :tc] for r in range(n_ring)]

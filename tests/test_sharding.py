@@ -12,6 +12,8 @@ from repro.launch.hlo_cost import analyze_text
 
 pytestmark = pytest.mark.slow     # JAX-lowering/compiling sharding tests: slow tier
 
+AUTO = jax.sharding.AxisType.Auto
+
 
 class TestParamRules:
     def test_rank_padding_for_stacked_layers(self):
@@ -96,7 +98,8 @@ class TestSmallMeshLowering:
                                       "jamba-v0.1-52b"])
     def test_lower_train_reduced(self, arch):
         from repro.launch.lowering import lower_cell
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AUTO, AUTO))
         cfg = get_config(arch).reduced()
         shape = ShapeConfig("t", "train", 32, 2)
         lowered = lower_cell(cfg, mesh, shape)
@@ -108,7 +111,8 @@ class TestSmallMeshLowering:
                                            ("whisper-medium", "prefill")])
     def test_lower_serving_reduced(self, arch, kind):
         from repro.launch.lowering import lower_cell
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AUTO, AUTO))
         cfg = get_config(arch).reduced()
         shape = ShapeConfig("t", kind, 64, 2)
         compiled = lower_cell(cfg, mesh, shape).compile()

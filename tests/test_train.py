@@ -19,6 +19,7 @@ from repro.train import optimizer as opt
 pytestmark = pytest.mark.slow     # JAX-compiling train-step tests: slow tier
 
 KEY = jax.random.PRNGKey(0)
+AUTO = jax.sharding.AxisType.Auto
 
 
 def _small_state(arch="minicpm-2b"):
@@ -110,7 +111,8 @@ class TestCheckpoint:
     def test_elastic_resharding_restore(self):
         """Restore under a (trivially different) mesh sharding."""
         _, state = _small_state()
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AUTO, AUTO))
         from repro.distributed import sharding as shd
         shardings = train_loop.TrainState(
             shd.param_shardings(state.params, mesh),

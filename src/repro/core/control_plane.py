@@ -19,6 +19,7 @@ import dataclasses
 import time as _time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import tracing
 from repro.core import elastic_sp, queues, rehoming, slack
 from repro.core.bmpr import BMPR, BMPRDecision
 from repro.core.types import ClusterView, Stream, Tier, Worker
@@ -101,52 +102,56 @@ class ControlPlane:
 
     # ---- the control tick (Algorithm 2 lines 7-15) ------------------------
     def tick(self, view: ClusterView, now: float) -> TickDecisions:
-        t0 = _time.perf_counter()
-        cfg = self.config
+        # one timer: the ``session/tick`` span and ``tick_times`` bracket
+        # the same interval (its two stamps sit just inside the span)
+        with tracing.span("session/tick"):
+            t0 = _time.perf_counter()
+            cfg = self.config
 
-        if cfg.vectorized and (not cfg.use_fidelity
-                               or hasattr(self.fidelity_policy,
-                                          "select_bulk")):
-            self._update_streams_vectorized(view, now)
-        else:
-            self._update_streams_scalar(view, now)
+            if cfg.vectorized and (not cfg.use_fidelity
+                                   or hasattr(self.fidelity_policy,
+                                              "select_bulk")):
+                self._update_streams_vectorized(view, now)
+            else:
+                self._update_streams_scalar(view, now)
 
-        queues.order_all(view)
+            queues.order_all(view)
 
-        # one tier-histogram pass shared by both planners (they plan
-        # back-to-back with no mutation in between, so sharing is exact)
-        counts = None
-        if cfg.use_rehoming or cfg.use_elastic_sp:
-            counts = queues.tier_counts(view)
+            # one tier-histogram pass shared by both planners (they plan
+            # back-to-back with no mutation in between, so sharing is
+            # exact)
+            counts = None
+            if cfg.use_rehoming or cfg.use_elastic_sp:
+                counts = queues.tier_counts(view)
 
-        migrations: List[rehoming.Migration] = []
-        if cfg.use_rehoming:
-            migrations = rehoming.plan_rehoming(view, now, counts=counts)
-            self.n_rehomings += len(migrations)
+            migrations: List[rehoming.Migration] = []
+            if cfg.use_rehoming:
+                migrations = rehoming.plan_rehoming(view, now, counts=counts)
+                self.n_rehomings += len(migrations)
 
-        sp_decisions: List[elastic_sp.SPDecision] = []
-        if cfg.use_elastic_sp:
-            just_migrated = {m.sid for m in migrations}
-            # vectorized tick: hoist the donor-quality signal (min
-            # resident credit per worker) to one pass instead of one
-            # scan per (negative stream, candidate donor) pair
-            donor_credits = (queues.min_credits(view) if cfg.vectorized
-                             else None)
-            sp_decisions = elastic_sp.plan_elastic_sp(
-                view, now, exclude=just_migrated, counts=counts,
-                donor_credits=donor_credits)
-            self.n_sp_events += sum(1 for d in sp_decisions
-                                    if d.kind == "expand")
+            sp_decisions: List[elastic_sp.SPDecision] = []
+            if cfg.use_elastic_sp:
+                just_migrated = {m.sid for m in migrations}
+                # vectorized tick: hoist the donor-quality signal (min
+                # resident credit per worker) to one pass instead of one
+                # scan per (negative stream, candidate donor) pair
+                donor_credits = (queues.min_credits(view) if cfg.vectorized
+                                 else None)
+                sp_decisions = elastic_sp.plan_elastic_sp(
+                    view, now, exclude=just_migrated, counts=counts,
+                    donor_credits=donor_credits)
+                self.n_sp_events += sum(1 for d in sp_decisions
+                                        if d.kind == "expand")
 
-        scale_out = 0
-        scale_in = 0
-        if self.front_door is not None:
-            scale_out = self.front_door.autoscale(view, now)
-            if scale_out == 0:
-                # never shed and add capacity in the same tick
-                scale_in = self.front_door.maybe_scale_in(view, now)
+            scale_out = 0
+            scale_in = 0
+            if self.front_door is not None:
+                scale_out = self.front_door.autoscale(view, now)
+                if scale_out == 0:
+                    # never shed and add capacity in the same tick
+                    scale_in = self.front_door.maybe_scale_in(view, now)
 
-        dt = _time.perf_counter() - t0
+            dt = _time.perf_counter() - t0
         self.tick_times.append(dt)
         return TickDecisions(migrations, sp_decisions, dt, scale_out,
                              scale_in)

@@ -35,6 +35,8 @@ Two modes, ONE workload spec and ONE metrics surface:
     PYTHONPATH=src python -m repro.launch.serve --real \
         --models ardit-self-forcing --published-widths \
         --streams 2 --chunks 3     # published widths, on an accelerator
+    PYTHONPATH=src python -m repro.launch.serve --real --batched \
+        --streams 2 --profile-dir /tmp/prof   # spans + device scopes
 """
 from __future__ import annotations
 
@@ -122,6 +124,14 @@ def main() -> None:
                          "270-point (cache-unlocked) frontier and "
                          "eligible denoise steps reuse cached residuals "
                          "(models/stepcache.py)")
+    ap.add_argument("--profile-dir", default="", metavar="DIR",
+                    help="--real: record a jax.profiler trace of the "
+                         "serving run into DIR (.xplane.pb, for "
+                         "TensorBoard or Perfetto): started after set-up, "
+                         "stopped when serving ends.  It holds the "
+                         "program's host spans (session/, batcher/, "
+                         "bmpr/select marks, with sid/chunk stats) and "
+                         "the device ops under their ardit/ scopes")
     ap.add_argument("--calibrate", action="store_true",
                     help="after a --real run, fit the sim cost model to "
                          "the session's measured EMAs, replay the same "
@@ -149,6 +159,9 @@ def main() -> None:
     if args.step_cache and not (args.real and args.batched):
         ap.error("--step-cache only applies to --real --batched (cache "
                  "hits ride the fused batched dispatch as no-op rows)")
+    if args.profile_dir and not args.real:
+        ap.error("--profile-dir only applies to --real (the simulator "
+                 "runs nothing on a device)")
     if args.calibrate and not args.real:
         ap.error("--calibrate only applies to --real (the sim IS the "
                  "model being calibrated)")
@@ -219,7 +232,16 @@ def main() -> None:
             verbose=True))   # --seed varies the workload, not the model
         for spec in specs:
             session.submit(spec)
-        res = session.run()
+        if args.profile_dir:
+            import jax
+            jax.profiler.start_trace(args.profile_dir)
+            try:
+                res = session.run()
+            finally:
+                jax.profiler.stop_trace()
+            print(f"  profile: {args.profile_dir}")
+        else:
+            res = session.run()
         s = summarize(res)
         label = (f"real-{args.lanes}-lane" if args.lanes > 1 else
                  "real-batched" if args.batched else "real-sequential")

@@ -386,12 +386,16 @@ def _chunk_forward_pages(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
         positions = q_off + jnp.arange(tc)                        # [Tc]
     ones = jnp.ones((d,), h.dtype)
 
+    # device scopes (``jax.named_scope``: op metadata, no run-time cost)
+    # name each part of a layer in a profiler trace; the attention's own
+    # scopes are in ``attention.paged_mha``
     def body(hh, xs):
         lp, li = xs["layer"], xs["index"]
-        mod = jax.nn.silu(temb) @ lp["mod"] + lp["mod_b"]         # [B,6D]
-        sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
-        a_in = _modulate(L.rmsnorm(hh, ones, cfg.norm_eps), sh1, sc1)
-        q, k, v = L.attn_qkv(cfg, lp["attn"], a_in, positions)
+        with jax.named_scope("ardit/qkv"):
+            mod = jax.nn.silu(temb) @ lp["mod"] + lp["mod_b"]     # [B,6D]
+            sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
+            a_in = _modulate(L.rmsnorm(hh, ones, cfg.norm_eps), sh1, sc1)
+            q, k, v = L.attn_qkv(cfg, lp["attn"], a_in, positions)
         outs = []
         for kp, vp, tbl, lo, hi in pools:
             if single:
@@ -407,13 +411,15 @@ def _chunk_forward_pages(cfg: ModelConfig, p: Params, x_chunk: jax.Array,
                                 n_kv_heads=hi - lo, sink=COND_TOKENS,
                                 chunk_tokens=tc)
             outs.append(o_s)
-        o = outs[0] if single else merge_head_shards(
-            outs, [hi - lo for (_, _, _, lo, hi) in pools])
-        o = o.reshape(b, tc, cfg.n_heads * cfg.head_dim)
-        hh = hh + g1[:, None, :] * shard(o @ lp["attn"]["wo"],
-                                         "batch", None, "embed")
-        f_in = _modulate(L.rmsnorm(hh, ones, cfg.norm_eps), sh2, sc2)
-        hh = hh + g2[:, None, :] * L.mlp_block(cfg, lp["mlp"], f_in)
+        with jax.named_scope("ardit/out_proj"):
+            o = outs[0] if single else merge_head_shards(
+                outs, [hi - lo for (_, _, _, lo, hi) in pools])
+            o = o.reshape(b, tc, cfg.n_heads * cfg.head_dim)
+            hh = hh + g1[:, None, :] * shard(o @ lp["attn"]["wo"],
+                                             "batch", None, "embed")
+        with jax.named_scope("ardit/mlp"):
+            f_in = _modulate(L.rmsnorm(hh, ones, cfg.norm_eps), sh2, sc2)
+            hh = hh + g2[:, None, :] * L.mlp_block(cfg, lp["mlp"], f_in)
         return hh, {"k": k, "v": v}
 
     xs = {"layer": p["layers"], "index": jnp.arange(cfg.n_layers)}

@@ -282,13 +282,16 @@ def paged_mha(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     from repro.kernels.paged_attention.ops import paged_chunk_attention
     b, sq, hq, d = q.shape
     scale = 1.0 / math.sqrt(d)
-    ctx = paged_chunk_attention(q, k_pages, v_pages, block_table,
-                                page_mask, layer, sink=sink,
-                                chunk_tokens=chunk_tokens)
-    own = _segment_attn(_group(q, n_kv_heads), chunk_k, chunk_v, None,
-                        scale)
-    out = _finalize(_merge(ctx, own), q.dtype)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
+    # device scopes of the AR-DiT step (``ardit._chunk_forward_pages``)
+    with jax.named_scope("ardit/paged_attn"):
+        ctx = paged_chunk_attention(q, k_pages, v_pages, block_table,
+                                    page_mask, layer, sink=sink,
+                                    chunk_tokens=chunk_tokens)
+    with jax.named_scope("ardit/segment_attn"):
+        own = _segment_attn(_group(q, n_kv_heads), chunk_k, chunk_v, None,
+                            scale)
+        out = _finalize(_merge(ctx, own), q.dtype)
+        return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, *,

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.core import queues
 from repro.core.fidelity import FidelityConfig
@@ -1240,134 +1241,147 @@ class BatchedChunkExecutor(ChunkExecutor):
                     # host-side — no device read on the decision path
                     cache_hits[i] = 1.0 / fid.steps
 
-        t0 = time.perf_counter()
-        if cache_hits and len(cache_hits) == len(sids):
-            # every row reuses its cached velocity: skip the jitted
-            # launch entirely — the attention+MLP stack is replaced by
-            # per-row AXPYs (this is the step cache's throughput win;
-            # ``dispatch_count`` does not advance)
-            self.cache_skipped_launches += 1
-            for i, (sid, f) in enumerate(zip(sids, flights)):
-                f.x = sc.apply_hit(sid, f.x, cache_hits[i])
-                f.step += 1
-            dt = time.perf_counter() - t0
-            for f in flights:
-                f.active_s += dt
-            return [], dt
-
-        bnd = self._boundary(sids, chunk_idx, fids, sp=sp)
-        x = (flights[0].x if len(flights) == 1
-             else jnp.concatenate([f.x for f in flights], axis=0))
-        t, dt_sig, is_dn = self._staging(
-            fids, tuple(f.step for f in flights), denoising)
-        self.dispatch_count += 1
-        if sp is not None:
-            x_new, new_kv = A.denoise_step_paged_sp(
-                self.cfg, self.params, x, t, dt_sig, self.pool.k,
-                self.pool.v, sp.pool.k, sp.pool.v, bnd["tables"],
-                bnd["tables_d"], bnd["dn"], bnd["cl"],
-                bnd["q_offset"], is_dn)
-        elif self.context_backend == "paged":
-            # context stays IN the pool: the step reads the current
-            # device buffers through the cached block tables (appends
-            # only ever touch pages outside every in-flight window, so
-            # the live read equals the boundary snapshot)
-            x_new, new_kv = A.denoise_step_paged(
-                self.cfg, self.params, x, t, dt_sig, self.pool.k,
-                self.pool.v, bnd["tables"], bnd["dn"], bnd["cl"],
-                bnd["q_offset"], is_dn)
-        else:
-            x_new, new_kv = A.denoise_step(
-                self.cfg, self.params, x, t, dt_sig, bnd["ctx_k"],
-                bnd["ctx_v"], bnd["q_offset"], bnd["dn"], bnd["cl"],
-                is_dn)
-
-        completed: List[int] = []
-        clean_rows: List[int] = []
-        for i, (sid, f) in enumerate(zip(sids, flights)):
-            if denoising[i]:
-                if i in cache_hits:
-                    # masked no-op row of a mixed launch: the row rode
-                    # along for shape stability; its output is the
-                    # cached AXPY — identical to the skipped-launch
-                    # path, so a hit never depends on group composition
+        # spans: ``batcher/launch`` holds the whole timed interval (t0 to
+        # dt) and, inside it, ``batcher/prep`` the host's work before the
+        # dispatch and ``batcher/sync`` the one wait for the device; their
+        # stats are read here, before t0, so that nothing but the spans'
+        # enter and exit lies between the stamps that feed the EMAs
+        clean = denoising.count(False)
+        first = denoising.index(False) if clean else 0
+        sync_sid, sync_chunk = sids[first], int(chunk_idx[first])
+        with tracing.span("batcher/launch", rows=len(sids), quant=quant,
+                          clean=clean):
+            t0 = time.perf_counter()
+            if cache_hits and len(cache_hits) == len(sids):
+                # every row reuses its cached velocity: skip the jitted
+                # launch entirely — the attention+MLP stack is replaced by
+                # per-row AXPYs (this is the step cache's throughput win;
+                # ``dispatch_count`` does not advance)
+                self.cache_skipped_launches += 1
+                for i, (sid, f) in enumerate(zip(sids, flights)):
                     f.x = sc.apply_hit(sid, f.x, cache_hits[i])
-                else:
-                    if fids[i].cache != "off":
-                        sc.record_step(sid, f.x, x_new[i:i + 1],
-                                       1.0 / fids[i].steps,
-                                       new_kv["k"][:, i])
-                    f.x = x_new[i:i + 1]
-                f.step += 1
+                    f.step += 1
+                dt = time.perf_counter() - t0
+                for f in flights:
+                    f.active_s += dt
+                return [], dt
+
+            with tracing.span("batcher/prep"):
+                bnd = self._boundary(sids, chunk_idx, fids, sp=sp)
+                x = (flights[0].x if len(flights) == 1
+                     else jnp.concatenate([f.x for f in flights], axis=0))
+                t, dt_sig, is_dn = self._staging(
+                    fids, tuple(f.step for f in flights), denoising)
+            self.dispatch_count += 1
+            if sp is not None:
+                x_new, new_kv = A.denoise_step_paged_sp(
+                    self.cfg, self.params, x, t, dt_sig, self.pool.k,
+                    self.pool.v, sp.pool.k, sp.pool.v, bnd["tables"],
+                    bnd["tables_d"], bnd["dn"], bnd["cl"],
+                    bnd["q_offset"], is_dn)
+            elif self.context_backend == "paged":
+                # context stays IN the pool: the step reads the current
+                # device buffers through the cached block tables (appends
+                # only ever touch pages outside every in-flight window, so
+                # the live read equals the boundary snapshot)
+                x_new, new_kv = A.denoise_step_paged(
+                    self.cfg, self.params, x, t, dt_sig, self.pool.k,
+                    self.pool.v, bnd["tables"], bnd["dn"], bnd["cl"],
+                    bnd["q_offset"], is_dn)
             else:
-                clean_rows.append(i)
-                completed.append(sid)
-        if clean_rows:
-            # effective window BEFORE the append advances chunk counts:
-            # the context this chunk's generation actually attended to
-            eff_w = {sids[i]: self.pool.effective_window(
-                sids[i], fids[i].window) for i in clean_rows}
-            rows = np.asarray(clean_rows)
-            self.pool.append([sids[i] for i in clean_rows],
-                             {"k": new_kv["k"][:, rows],
-                              "v": new_kv["v"][:, rows]}, quant)
-            for i in clean_rows:
-                row = {"k": new_kv["k"][:, i:i + 1],
-                       "v": new_kv["v"][:, i:i + 1]}
-                link = self.sp_links.get(sids[i])
-                if link is not None:
-                    # the donor's half-head mirror must track the home
-                    # pool: ring-write this chunk's upper half into the
-                    # donor page set so the next SP2 boundary sees
-                    # consistent halves (solo mode only — the assertion
-                    # above keeps batch-linked streams off this lane)
-                    self._append_sp_half(link, sids[i], row, quant)
-                guest = self.sp_guests.get(sids[i])
-                if guest is not None:
-                    # batch-axis SP shipback: the guest's home pool is
-                    # the system of record — append the full-head chunk
-                    # there too (a real cross-device put when the lanes
-                    # are device-backed), so release never moves state
-                    guest.pool.append([sids[i]], row, quant)
-            now_wall = None
-            for i in clean_rows:
-                sid = sids[i]
-                fid = fids[i]
-                f = self.inflight.pop(sid)
-                self.chunks[sid].append(f.x)
-                self.fidelity_log[sid].append(fid.key)
-                self.effective_window_log.setdefault(sid, []).append(
-                    eff_w[sid])
-                self.chunk_seq[sid] = self.chunk_seq.get(sid, 0) + 1
-                if now_wall is None:        # one sync per completion step
-                    f.x.block_until_ready()
-                    now_wall = time.perf_counter()
-                # measured chunk wall -> timing priors, attributed to
-                # each completing row's OWN fidelity key: under fused
-                # dispatch ``active_s`` accrued per launch the row was
-                # live in, so a fused launch's latency lands on member
-                # keys weighted by the steps each member actually rode
-                # — BMPR budgets and routing see the same per-fidelity
-                # costs as under split dispatch.  Only time spent IN
-                # the batch counts (a stream held out mid-chunk accrues
-                # no active time).  Spill/restore dispatcher waits
-                # charged by the transfer engine ride on the chunk they
-                # delayed.
-                lat = (f.active_s + (now_wall - t0)
-                       + self._pending_wait.pop(sid, 0.0))
-                self.latency_ema[fid.key] = (
-                    EMA_DECAY * self.latency_ema.get(fid.key, lat)
-                    + (1.0 - EMA_DECAY) * lat)
-                step = lat / (fid.steps + 1)
-                self.step_ema[fid.key] = (
-                    EMA_DECAY * self.step_ema.get(fid.key, step)
-                    + (1.0 - EMA_DECAY) * step)
-        dt = time.perf_counter() - t0
-        for sid in sids:
-            f = self.inflight.get(sid)
-            if f is not None:               # still mid-chunk
-                f.active_s += dt
-        return completed, dt
+                x_new, new_kv = A.denoise_step(
+                    self.cfg, self.params, x, t, dt_sig, bnd["ctx_k"],
+                    bnd["ctx_v"], bnd["q_offset"], bnd["dn"], bnd["cl"],
+                    is_dn)
+
+            completed: List[int] = []
+            clean_rows: List[int] = []
+            for i, (sid, f) in enumerate(zip(sids, flights)):
+                if denoising[i]:
+                    if i in cache_hits:
+                        # masked no-op row of a mixed launch: the row rode
+                        # along for shape stability; its output is the
+                        # cached AXPY — identical to the skipped-launch
+                        # path, so a hit never depends on group composition
+                        f.x = sc.apply_hit(sid, f.x, cache_hits[i])
+                    else:
+                        if fids[i].cache != "off":
+                            sc.record_step(sid, f.x, x_new[i:i + 1],
+                                           1.0 / fids[i].steps,
+                                           new_kv["k"][:, i])
+                        f.x = x_new[i:i + 1]
+                    f.step += 1
+                else:
+                    clean_rows.append(i)
+                    completed.append(sid)
+            if clean_rows:
+                # effective window BEFORE the append advances chunk counts:
+                # the context this chunk's generation actually attended to
+                eff_w = {sids[i]: self.pool.effective_window(
+                    sids[i], fids[i].window) for i in clean_rows}
+                rows = np.asarray(clean_rows)
+                self.pool.append([sids[i] for i in clean_rows],
+                                 {"k": new_kv["k"][:, rows],
+                                  "v": new_kv["v"][:, rows]}, quant)
+                for i in clean_rows:
+                    row = {"k": new_kv["k"][:, i:i + 1],
+                           "v": new_kv["v"][:, i:i + 1]}
+                    link = self.sp_links.get(sids[i])
+                    if link is not None:
+                        # the donor's half-head mirror must track the home
+                        # pool: ring-write this chunk's upper half into the
+                        # donor page set so the next SP2 boundary sees
+                        # consistent halves (solo mode only — the assertion
+                        # above keeps batch-linked streams off this lane)
+                        self._append_sp_half(link, sids[i], row, quant)
+                    guest = self.sp_guests.get(sids[i])
+                    if guest is not None:
+                        # batch-axis SP shipback: the guest's home pool is
+                        # the system of record — append the full-head chunk
+                        # there too (a real cross-device put when the lanes
+                        # are device-backed), so release never moves state
+                        guest.pool.append([sids[i]], row, quant)
+                now_wall = None
+                for i in clean_rows:
+                    sid = sids[i]
+                    fid = fids[i]
+                    f = self.inflight.pop(sid)
+                    self.chunks[sid].append(f.x)
+                    self.fidelity_log[sid].append(fid.key)
+                    self.effective_window_log.setdefault(sid, []).append(
+                        eff_w[sid])
+                    self.chunk_seq[sid] = self.chunk_seq.get(sid, 0) + 1
+                    if now_wall is None:    # one sync per completion step
+                        with tracing.span("batcher/sync", sid=sync_sid,
+                                          chunk=sync_chunk):
+                            f.x.block_until_ready()
+                        now_wall = time.perf_counter()
+                    # measured chunk wall -> timing priors, attributed to
+                    # each completing row's OWN fidelity key: under fused
+                    # dispatch ``active_s`` accrued per launch the row was
+                    # live in, so a fused launch's latency lands on member
+                    # keys weighted by the steps each member actually rode
+                    # — BMPR budgets and routing see the same per-fidelity
+                    # costs as under split dispatch.  Only time spent IN
+                    # the batch counts (a stream held out mid-chunk accrues
+                    # no active time).  Spill/restore dispatcher waits
+                    # charged by the transfer engine ride on the chunk they
+                    # delayed.
+                    lat = (f.active_s + (now_wall - t0)
+                           + self._pending_wait.pop(sid, 0.0))
+                    self.latency_ema[fid.key] = (
+                        EMA_DECAY * self.latency_ema.get(fid.key, lat)
+                        + (1.0 - EMA_DECAY) * lat)
+                    step = lat / (fid.steps + 1)
+                    self.step_ema[fid.key] = (
+                        EMA_DECAY * self.step_ema.get(fid.key, step)
+                        + (1.0 - EMA_DECAY) * step)
+            dt = time.perf_counter() - t0
+            for sid in sids:
+                f = self.inflight.get(sid)
+                if f is not None:               # still mid-chunk
+                    f.active_s += dt
+            return completed, dt
 
     def _append_sp_half(self, link: SPLink, sid: int,
                         new_kv: Dict[str, jax.Array], quant: str) -> None:

@@ -58,6 +58,7 @@ import itertools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from repro import tracing
 from repro.core import elastic_sp, queues, rehoming, slack
 from repro.core.bmpr import BMPR, BMPRDecision
 from repro.core.control_plane import (ControlConfig, ControlPlane,
@@ -566,17 +567,18 @@ class StreamingSession:
                                           b.top_latency, model=b.name)
 
     def _on_arrival(self, sid: int, t_arr: float) -> None:
-        self._pending_arrivals -= 1
-        first_est = self._first_estimate(sid)
-        if self.front_door is not None:
-            dec = self.front_door.on_arrival(self.view, t_arr,
-                                             first_est, sid)
-            if dec.action == "reject":
-                self._n_rejected += 1
-                return
-            if dec.action == "queue":
-                return         # promoted by _drain_front_door (or shed)
-        self._admit_stream(sid, t_arr, first_est)
+        with tracing.span("session/arrival", sid=sid):
+            self._pending_arrivals -= 1
+            first_est = self._first_estimate(sid)
+            if self.front_door is not None:
+                dec = self.front_door.on_arrival(self.view, t_arr,
+                                                 first_est, sid)
+                if dec.action == "reject":
+                    self._n_rejected += 1
+                    return
+                if dec.action == "queue":
+                    return     # promoted by _drain_front_door (or shed)
+            self._admit_stream(sid, t_arr, first_est)
 
     def _admit_stream(self, sid: int, t_arr: float,
                       first_est: float) -> None:
@@ -599,10 +601,11 @@ class StreamingSession:
         self.view.streams[sid] = s
         self.workers[home].queue.append(sid)
         model = bundle.name if bundle is not None else None
-        self.lanes.admit(
-            sid, home, seed=sid, streams=self.view.streams,
-            protect=list(self.lanes.ex_for(home, model).inflight),
-            model=model)
+        with tracing.span("session/admit", sid=sid):     # sink K/V
+            self.lanes.admit(
+                sid, home, seed=sid, streams=self.view.streams,
+                protect=list(self.lanes.ex_for(home, model).inflight),
+                model=model)
 
     def _on_prompt_switch(self, sid: int, now: float) -> None:
         s = self.view.streams.get(sid)
@@ -875,6 +878,8 @@ class StreamingSession:
         dec = (sel(s.model, budget)
                if sel is not None and s.model is not None
                else pol.select(budget))
+        tracing.mark("bmpr/select", sid=sid, chunk=s.chunks_done,
+                     mode=dec.mode, fid=dec.fidelity.key)
         s.next_fidelity = dec.fidelity
         s.t_next = dec.latency
         s.chunk_started = now

@@ -93,3 +93,58 @@ def test_denoise_step_paged_compiles(one_chip, monkeypatch):
         _spec((1,), jnp.int32, one_chip),
         _spec((1,), jnp.bool_, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _body_ops(hlo: str):
+    """(instruction, op_name) of the top-level instructions of the
+    compiled layer loop's body."""
+    import re
+    body = re.search(r"body=%([\w.\-]+)", hlo).group(1)
+    comp = hlo.split(f"\n%{body} ", 1)[1].split("\n}", 1)[0]
+    out = []
+    for line in comp.split("\n")[1:]:
+        name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if name and op_name:
+            out.append((name.group(1), op_name.group(1)))
+    return out
+
+
+def test_denoise_step_device_scopes(one_chip, monkeypatch):
+    """The benchmark's step shape (batch of 2, masked, 4,680-token
+    chunks on 15 layers) as the TPU compiler fuses it: every operation of
+    the layer body lies in one of the five ``ardit/*`` scopes that a
+    device trace is read by, the Pallas kernel in ``ardit/paged_attn``
+    and both MLP matmuls in ``ardit/mlp``.  Only the loop's own slicing
+    of the stacked weights and KV outputs is outside them."""
+    import dataclasses
+    import re
+    import repro.kernels.paged_attention.ops as ops
+    monkeypatch.setattr(ops, "kernel_mode", lambda: "pallas")
+    cfg = dataclasses.replace(get_config("ardit-self-forcing"),
+                              n_layers=15, ardit_frame_tokens=1560)
+    params = jax.tree_util.tree_map(
+        lambda s: _spec(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: A.init_params(cfg, jax.random.PRNGKey(0))))
+    tc, page = A.chunk_tokens(cfg), A.page_tokens(cfg)
+    n, b = 1 + cfg.ardit_window_chunks, 2
+    pool = _spec((cfg.n_layers, b * n + 1, cfg.n_kv_heads, page,
+                  cfg.head_dim), jnp.bfloat16, one_chip)
+    f32 = _spec((b,), jnp.float32, one_chip)
+    hlo = A.denoise_step_paged.lower(
+        cfg, params, _spec((b, tc, A.LATENT_CH), jnp.float32, one_chip),
+        f32, f32, pool, pool, _spec((b, n), jnp.int32, one_chip),
+        _spec((b, n * page), jnp.bool_, one_chip), None,
+        _spec((b,), jnp.int32, one_chip),
+        _spec((b,), jnp.bool_, one_chip)).compile().as_text()
+    scope = re.compile(r"ardit/(qkv|paged_attn|segment_attn|out_proj|mlp)/")
+    ops_ = _body_ops(hlo)
+    layer = [(i, o) for i, o in ops_ if "/closed_call/" in o]
+    assert layer and all(len(scope.findall(o)) == 1 for _, o in layer)
+    assert {scope.search(o).group(1) for _, o in layer} == {
+        "qkv", "paged_attn", "segment_attn", "out_proj", "mlp"}
+    outside = {o.rsplit("/", 1)[1] for i, o in ops_ if (i, o) not in layer}
+    assert outside <= {"dynamic_slice", "dynamic_update_slice", "add"}
+    pallas = [o for i, o in layer if i.startswith("paged_chunk_attention")]
+    assert pallas and all("ardit/paged_attn/" in o for o in pallas)
+    assert sum(o.endswith("ardit/mlp/dot_general") for _, o in layer) == 2

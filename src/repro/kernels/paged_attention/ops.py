@@ -29,7 +29,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_table, page_mask,
     block_table [B,n]; page_mask [B,n*page] bool.
     ``sink``/``chunk_tokens`` optionally declare the valid prefix of the
     sink page / ring pages so the oracle can skip always-masked page
-    tails (the Pallas kernel skips them per context tile);
+    tails (the Pallas kernel never reads them);
     ``page_mask=None`` (hint required) is the all-visible fast
     path that skips per-score masking.  ``page_mask`` is per-ROW, so a
     single launch serves rows with different fidelity windows and

@@ -151,20 +151,56 @@ class TestPagedChunkAttention:
             np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
                                        err_msg=name)
 
+    @pytest.mark.parametrize("tq,page,d,kv_bytes,masked,tiles", [
+        # the tiled test below: the last sub-tile cut at 1,000 tokens
+        (528, 1152, 8, 4, True, [(0, 512, 512), (512, 512, 488)]),
+        # ardit-causal-forcing: 880-row query tiles, 2,688-token pages
+        (880, 2688, 96, 2, False, [(lo, 512, 512) for lo in
+                                   range(0, 2560, 512)]
+         + [(2560, 128, 80)]),
+        # ardit-self-forcing at 480p: 944-row query tiles, 4,736-token
+        # (37 x 128) pages, bf16 and fp8 pools
+        (944, 4736, 128, 2, False, [(lo, 512, 512) for lo in
+                                    range(0, 4608, 512)]
+         + [(4608, 128, 72)]),
+        (944, 4736, 128, 1, True, [(lo, 512, 512) for lo in
+                                   range(0, 4608, 512)]
+         + [(4608, 128, 72)]),
+    ])
+    def test_sub_tile_rule(self, tq, page, d, kv_bytes, masked, tiles):
+        """The kernel walks a page's valid prefix (ring pages: the
+        chunk, here 4,680 tokens for the 4,736-token page, 2,640 for the
+        2,688-token page, 1,000 for the 1,152-token one) in sub-tiles
+        sized from the shapes and the scoped-VMEM budget; the sink's 77
+        tokens take one 128-token sub-tile; a page that is not
+        128-aligned is one sub-tile."""
+        from repro.kernels.paged_attention.kernel import (
+            context_tile, sub_tiles)
+        extent = {1152: 1000, 2688: 2640, 4736: 4680}[page]
+        tk = context_tile(tq, page, d, 2 if kv_bytes < 4 else 4, kv_bytes,
+                          masked)
+        assert list(sub_tiles(extent, page, tk)) == tiles
+        assert sub_tiles(77, page, tk) == ((0, 128, 77),)
+        assert context_tile(tq, page + 8, d, 2, kv_bytes, masked) == page + 8
+        assert sub_tiles(70, 72, 72) == ((0, 72, 70),)
+
     @pytest.mark.parametrize("masked", [True, False])
     def test_tiled_layer_stacked_vs_ref(self, masked):
         """Two query tiles (r = 1,040 rows: two 528-row tiles, the last
-        16 rows padding) and three 384-token context tiles per
-        1,152-token page, reading layer 1 of a layer-stacked pool in
-        place; the sink page's dead tiles are skipped."""
+        16 rows padding) over 1,152-token pages, reading layer 1 of a
+        layer-stacked pool in place; a ring page's 1,000 valid tokens
+        are two 512-token sub-tiles, the last cut at 488, and the sink's
+        77 one 128-token sub-tile; the page tails are never read."""
         from repro.kernels.paged_attention.kernel import (
-            kv_tile, paged_chunk_attention_pallas, query_tile)
+            context_tile, paged_chunk_attention_pallas, query_tile,
+            sub_tiles)
         from repro.kernels.paged_attention.ref import \
             paged_chunk_attention_ref
         B, Sq, Hq, Hkv, D, page, npg, ptot = 2, 520, 4, 2, 8, 1152, 3, 8
         sink, tc = 77, 1000
         assert query_tile(Sq * Hq // Hkv) == (528, 1056)
-        assert kv_tile(page) == 384
+        tk = context_tile(528, page, D, 4, 4, masked)
+        assert sub_tiles(tc, page, tk) == ((0, 512, 512), (512, 512, 488))
         ks = jax.random.split(KEY, 5)
         q = jax.random.normal(ks[0], (B, Sq, Hq, D))
         kp = jax.random.normal(ks[1], (2, ptot, Hkv, page, D))
@@ -190,8 +226,9 @@ class TestPagedChunkAttention:
     def test_published_head_shape_vs_ref(self, D):
         """One KV head at published widths (2,640-token chunk queries,
         2,688-token pages, head_dim 128 or 96 — the two AR-DiT
-        configs): three 880-row query tiles, three 896-token context
-        tiles per page, a sparsified mask."""
+        configs): three 880-row query tiles, each page's 2,640-token
+        prefix in five 512-token context sub-tiles and a sixth cut at 80
+        tokens, a sparsified mask."""
         from repro.kernels.paged_attention.kernel import \
             paged_chunk_attention_pallas
         from repro.kernels.paged_attention.ref import \
@@ -214,6 +251,72 @@ class TestPagedChunkAttention:
             np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
                                        err_msg=name)
 
+
+    @pytest.mark.parametrize("masked", [True, False])
+    def test_sink_wider_than_ring_vs_ref(self, masked):
+        """A sink prefix (300 tokens) longer than the ring pages' (100) on
+        384-token pages: the sink is one 384-token sub-tile, a ring page
+        one of 128, and the sink's visible tokens all lie past the ring
+        sub-tile's end."""
+        from repro.kernels.paged_attention.kernel import \
+            paged_chunk_attention_pallas
+        from repro.kernels.paged_attention.ref import \
+            paged_chunk_attention_ref
+        B, Sq, D, page, n, sink, tc = 2, 8, 16, 384, 3, 300, 100
+        ks = jax.random.split(KEY, 4)
+        q = jax.random.normal(ks[0], (B, Sq, 2, D))
+        kp = jax.random.normal(ks[1], (6, 1, page, D))
+        vp = jax.random.normal(ks[2], (6, 1, page, D))
+        bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
+        mask = None
+        if masked:
+            m = np.zeros((B, n, page), bool)
+            m[:, 0, 200:sink] = True
+            m[:, 1:, :tc] = np.array(jax.random.uniform(ks[3], (B, 2, tc))
+                                     < 0.5)
+            mask = jnp.asarray(m.reshape(B, -1))
+        got = paged_chunk_attention_pallas(q, kp, vp, bt, mask, sink=sink,
+                                           chunk_tokens=tc, interpret=True)
+        want = paged_chunk_attention_ref(q, kp, vp, bt, mask, sink=sink,
+                                         chunk_tokens=tc)
+        for g, w, name in zip(got, want, ("m", "l", "acc")):
+            np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("masked", [True, False])
+    @pytest.mark.parametrize("kv_dtype", ["bfloat16", "float8_e4m3fn"])
+    def test_prime_page_vs_ref(self, kv_dtype, masked):
+        """The served 480p page geometry at one KV head: 64 query rows
+        against 4,736-token pages, 37 x 128, for which no large divisor
+        exists, from a bf16 or an fp8 pool.  Ring pages are walked in
+        512-token sub-tiles whose last slice is cut at the 4,680-token
+        chunk, the sink page in one 128-token sub-tile cut at 77.  The
+        masked case sparsifies every page and leaves stream 1's last page
+        dead (its blocks re-pointed, its sub-tiles skipped)."""
+        from repro.kernels.paged_attention.kernel import \
+            paged_chunk_attention_pallas
+        from repro.kernels.paged_attention.ref import \
+            paged_chunk_attention_ref
+        B, sq, page, n, sink, tc, D = 2, 64, 4736, 3, 77, 4680, 128
+        ks = jax.random.split(KEY, 4)
+        q = jax.random.normal(ks[0], (B, sq, 1, D), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (5, 1, page, D)).astype(kv_dtype)
+        vp = jax.random.normal(ks[2], (5, 1, page, D)).astype(kv_dtype)
+        bt = jnp.asarray([[4, 0, 2], [1, 3, 0]], jnp.int32)
+        mask = None
+        if masked:
+            m = np.array(jax.random.uniform(ks[3], (B, n, page)) < 0.5)
+            m[:, 0, sink:] = False
+            m[:, 1:, tc:] = False
+            m[1, 2] = False                     # a dead page
+            mask = jnp.asarray(m.reshape(B, -1))
+        got = paged_chunk_attention_pallas(q, kp, vp, bt, mask, sink=sink,
+                                           chunk_tokens=tc, interpret=True)
+        want = paged_chunk_attention_ref(q, kp, vp, bt, mask, sink=sink,
+                                         chunk_tokens=tc)
+        for g, w, name in zip(got, want, ("m", "l", "acc")):
+            np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3,
+                                       err_msg=name)
 
 @pytest.mark.parametrize("backend,env,want", [
     ("cpu", None, "ref"),

@@ -53,17 +53,24 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "float8_e4m3fn"])
 @pytest.mark.parametrize("masked", [False, True])
 @pytest.mark.parametrize("arch", ARCHS)
-def test_paged_chunk_kernel_compiles(one_chip, arch, masked):
+def test_paged_chunk_kernel_compiles(one_chip, arch, masked, kv_dtype):
     """One layer's chunk queries against a full window of head-major
-    pages (sink + 7 ring pages), all-visible and masked."""
+    pages (sink + 7 ring pages), all-visible and masked, from a bf16 or
+    an fp8 pool.  ardit-self-forcing at 832x480 (1,560 tokens a latent
+    frame: 4,680-token chunks, 4,736-token pages, 37 x 128),
+    ardit-causal-forcing at its 880-token frames (2,688-token pages)."""
+    import dataclasses
     cfg = get_config(arch)
+    if arch == "ardit-self-forcing":
+        cfg = dataclasses.replace(cfg, ardit_frame_tokens=1560)
     tc, page = A.chunk_tokens(cfg), A.page_tokens(cfg)
     n = 1 + cfg.ardit_window_chunks
     q = _spec((1, tc, cfg.n_heads, cfg.head_dim), jnp.bfloat16, one_chip)
     pages = _spec((n + 1, cfg.n_kv_heads, page, cfg.head_dim),
-                  jnp.bfloat16, one_chip)
+                  jnp.dtype(kv_dtype), one_chip)
     table = _spec((1, n), jnp.int32, one_chip)
     mask = _spec((1, n * page), jnp.bool_, one_chip) if masked else None
     compiled = jax.jit(lambda q, k, v, t, m: paged_chunk_attention_pallas(

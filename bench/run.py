@@ -8,7 +8,9 @@ Everything it needs is named in ``BENCHMARK.json`` and found by name under
 traffic (``traffic/<traffic>.json``), and one reader per per-layer metric
 (``metrics/<metric>.py``).  The run
 
-1. makes the weights from ``--seed`` on the device, builds the server
+1. checks that the configuration file states the condition and latent
+   sizes the program states for it (``check_layout``), makes the weights
+   from ``--seed`` on the device, builds the server
    (``StreamingSession`` over the batched paged executor, BMPR over the
    90-point fidelity space, the default front door, 0.75 s playout),
    compiles every program the window can launch, and starts the traffic's
@@ -22,6 +24,11 @@ traffic (``traffic/<traffic>.json``), and one reader per per-layer metric
 4. replays a sample of the chunks made in the window, drawn from the
    seed, through the float32 reference (``reference/``) and compares
    each of them.
+
+A configuration names its float32 reference module (``reference/<name>.py``
+by its ``reference`` key).  The run reads eight functions of it:
+``dims_of``, ``make_params``, ``parse_fidelity``, ``visible_window_tokens``,
+``passes_of``, ``replay_stream``, ``chunk_noise`` and ``rel_err``.
 
 Its last line on standard output is one JSON object; the compared numbers
 and their limits close standard error.  It exits non-zero, printing no
@@ -61,6 +68,34 @@ CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 def load_json(*parts: str) -> Dict:
     with open(os.path.join(ROOT, *parts)) as f:
         return json.load(f)
+
+
+def model_config(conf: Dict):
+    """The program's ``ModelConfig`` as the configuration file states it."""
+    from repro.configs.base import get_config
+    return dataclasses.replace(get_config(conf["arch"]), name=conf["arch"],
+                               **conf["model"])
+
+
+def check_layout(conf: Dict, A, mcfg) -> None:
+    """Exit, naming the key and both values, where the configuration file
+    states other condition or latent sizes than the program ``A`` does for
+    ``mcfg``: condition tokens in the self-attention sink, condition tokens
+    read by a cross-attention (``text_tokens``, 0 where the file leaves it
+    out), latent values per token.  The program states them through its
+    ``io_layout(cfg)``, a mapping of those three keys; a program without
+    one has one block for every
+    configuration, with ``COND_TOKENS`` in the sink, no cross-attention and
+    ``LATENT_CH`` channels."""
+    io_layout = getattr(A, "io_layout", None)
+    have = ({"cond_tokens": A.COND_TOKENS, "text_tokens": 0,
+             "latent_channels": A.LATENT_CH} if io_layout is None
+            else io_layout(mcfg))
+    for key in ("cond_tokens", "text_tokens", "latent_channels"):
+        want = conf.get(key, 0 if key == "text_tokens" else None)
+        if have[key] != want:
+            raise SystemExit(f"{key}: the configuration file states {want}, "
+                             f"the program {have[key]}")
 
 
 def cell_files(workload: str):
@@ -198,7 +233,6 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import numpy as np
-    from repro.configs.base import get_config
     from repro.models import ardit as A
     from repro.sched_sim.frontdoor import FrontDoorConfig
     from repro.sched_sim.workloads import StreamSpec
@@ -213,15 +247,11 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     if require_chip and dev.device_kind not in peaks:
         raise SystemExit(f"no peaks for device kind {dev.device_kind!r}")
     peak = peaks.get(dev.device_kind, next(iter(peaks.values())))
-    if (A.COND_TOKENS, A.LATENT_CH) != (conf["cond_tokens"],
-                                        conf["latent_channels"]):
-        raise SystemExit("the program's condition/latent sizes differ from "
-                         "the configuration file")
+    mcfg = model_config(conf)
+    check_layout(conf, A, mcfg)
     dims = reference.dims_of(conf)
     params = reference.make_params(dims, seed, conf["model"]["param_dtype"])
     jax.block_until_ready(params)
-    mcfg = dataclasses.replace(get_config(conf["arch"]), name=conf["arch"],
-                               **conf["model"])
     bundle = resolve_bundle(mcfg, params=params)
     cadence = traffic["playout_s_per_chunk"]
     ramp = traffic["ramp_s"]

@@ -1,10 +1,15 @@
 """The whole run at reduced widths on the CPU: the timed path against the
-float32 reference, its fp8 control, a planted fault, and the refusal of a
-machine without a TPU."""
+float32 reference, its fp8 control, a planted fault, the refusal of a
+machine without a TPU and of a configuration file whose condition or
+latent sizes are not the program's, and what the run reads of a
+configuration's reference module."""
+import glob
+import importlib
 import json
 import os
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -25,20 +30,46 @@ def reduced_conf():
     return conf
 
 
-def run(seed, **kw):
+def run(seed, conf=None, **kw):
     from bench import run as bench_run
     traffic = bench_run.cell_files(WORKLOAD)[3]
     # the reduced model serves a stream in well under a second: arrive
     # often enough that the 6 s window holds several
     return bench_run.run_cell(WORKLOAD, seed, 6.0, False, require_chip=False,
-                              conf_override=reduced_conf(),
+                              conf_override=conf or reduced_conf(),
                               traffic_override=dict(traffic, rate_per_s=1.0),
                               **kw)
 
 
+# what run_cell may read of a configuration's reference module
+REFERENCE_CONTRACT = {"dims_of", "make_params", "parse_fidelity",
+                      "visible_window_tokens", "passes_of", "replay_stream",
+                      "chunk_noise", "rel_err"}
+
+
+class Recording(types.ModuleType):
+    """A module that records the names read of it."""
+
+    def __init__(self, module):
+        super().__init__(module.__name__)
+        self._module, self.read = module, set()
+
+    def __getattr__(self, name):
+        self.read.add(name)
+        return getattr(self._module, name)
+
+
 @pytest.fixture(scope="module")
 def sound():
-    return run(2 ** 33 + 5)
+    """A sound run, with the reference module it imports recording what
+    the run reads of it."""
+    name = "bench.reference.ardit"
+    recording = Recording(importlib.import_module(name))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setitem(sys.modules, name, recording)
+        out = run(2 ** 33 + 5)
+    out["reference_read"] = recording.read
+    return out
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +89,11 @@ def test_sound_run_is_correct(sound):
     assert info["residency"]["evictions"] == 0
     assert info["pool_streams"] == 2
     assert set(out["metrics"]) == {"chunks_per_s", "setup_s"}
+
+
+def test_run_reads_the_documented_reference_functions(sound):
+    """A new configuration's reference module provides these and no more."""
+    assert sound["reference_read"] == REFERENCE_CONTRACT
 
 
 def test_compared_chunks_are_made_in_the_window(sound):
@@ -127,3 +163,44 @@ def test_sample_prefers_uncovered_features_within_budget():
                                     reference=ref)
     assert picks == {1: [5], 2: [2]}
     assert ref.passes_of(keys[1], [4, 5]) == 5 + 2 + 2
+
+
+@pytest.mark.parametrize("key,value", [("text_tokens", 512),
+                                       ("cond_tokens", 78),
+                                       ("latent_channels", 8)])
+def test_refuses_sizes_the_program_does_not_state(monkeypatch, key, value):
+    """A configuration file whose condition or latent sizes differ from
+    the program's ends the run, naming the key, before any weights."""
+    from bench.reference import ardit as ref
+
+    def no_weights(*a, **k):
+        raise AssertionError("make_params was called")
+
+    monkeypatch.setattr(ref, "make_params", no_weights)
+    conf = dict(reduced_conf(), **{key: value})
+    with pytest.raises(SystemExit, match=f"^{key}: .*states {value}, "):
+        run(3, conf)
+
+
+@pytest.mark.parametrize("path", sorted(glob.glob(
+    os.path.join(ROOT, "bench", "configs", "*.json"))),
+    ids=os.path.basename)
+def test_configuration_states_the_programs_sizes(path):
+    from bench import run as bench_run
+    from repro.models import ardit as A
+    with open(path) as f:
+        conf = json.load(f)
+    bench_run.check_layout(conf, A, bench_run.model_config(conf))
+
+
+def test_layout_comes_from_the_program_where_it_states_one():
+    """A program with ``io_layout`` is asked for each configuration's
+    sizes: a block reading 512 text tokens through a cross-attention over
+    an empty sink passes a file that states so, and no other."""
+    from bench import run as bench_run
+    program = types.SimpleNamespace(io_layout=lambda cfg: {
+        "cond_tokens": 0, "text_tokens": 512, "latent_channels": 16})
+    conf = dict(reduced_conf(), cond_tokens=0, text_tokens=512)
+    bench_run.check_layout(conf, program, None)
+    with pytest.raises(SystemExit, match="^cond_tokens: "):
+        bench_run.check_layout(reduced_conf(), program, None)
